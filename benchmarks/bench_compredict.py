@@ -149,7 +149,7 @@ def run_features():
     tabs = _synthetic_partitions(32, 100, seed=1)
     enc = encode_dtype_classes(tabs)
     t0 = time.perf_counter()
-    extract_features_batch(tabs, "col", "bucketed", "pallas", encoded=enc)
+    extract_features_batch(tabs, "col", "bucketed", "interpret", encoded=enc)
     rows.append(row("features/N32/pallas_interpret",
                     (time.perf_counter() - t0) * 1e6))
     return emit(rows, "feature_backends")

@@ -1,15 +1,8 @@
-"""jax API-drift shims, consolidated (ROADMAP carry-over).
+"""The jax 0.9 mesh and shard_map surface, in one module.
 
-Every version-gated jax surface the repo touches lives here, so the rest of
-the codebase imports one module instead of scattering ``hasattr`` probes:
-
-* :func:`mesh_context` — ``jax.set_mesh`` vs the legacy Mesh-as-context
-  manager global-mesh API.
-* :func:`shard_map` — ``jax.shard_map`` vs ``jax.experimental.shard_map``,
-  with the replication-check kwarg (``check_rep`` -> ``check_vma`` rename)
-  picked from the target's signature.
-* :func:`make_mesh` — ``jax.make_mesh`` with the ``AxisType`` kwarg gated
-  on availability (older jax defaults every axis to Auto anyway).
+* :func:`mesh_context` — ``jax.set_mesh``.
+* :func:`shard_map` — ``jax.shard_map`` with its ``check_vma`` flag.
+* :func:`make_mesh` — ``jax.make_mesh`` with every axis explicitly Auto.
 
 ``repro.distributed.ctx`` and ``repro.launch.mesh`` re-export these for
 their existing call sites; new code should import ``repro.compat``
@@ -18,41 +11,21 @@ directly.
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh`` on new jax; on older versions the Mesh object itself
-    is the (legacy global-mesh) context manager with the same effect."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager that makes ``mesh`` the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` on new jax, the experimental module on older jax.
-    The replication-check kwarg is picked from the target's signature
-    (``check_rep`` was renamed ``check_vma`` independently of the function's
-    promotion out of jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = {}
-    if check_vma is not None:
-        params = inspect.signature(sm).parameters
-        kw = {"check_vma" if "check_vma" in params else "check_rep":
-              check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with every axis explicitly Auto when the
-    ``AxisType`` enum exists; older versions default to Auto without it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -28,8 +28,9 @@ from repro.storage.codecs import Codec, default_codecs, measure
 #: Selectable feature-extraction backends (see :func:`extract_features_batch`):
 #: 'numpy' is the per-partition string/unique loop; 'jnp' and 'pallas' run
 #: the batched device pipeline in kernels/entropy_features.py on a one-pass
-#: dictionary encoding of all N partitions.
-FEATURE_BACKENDS = ("numpy", "jnp", "pallas")
+#: dictionary encoding of all N partitions; 'interpret' runs the Pallas
+#: program in the interpreter (CPU tests).
+FEATURE_BACKENDS = ("numpy", "jnp", "pallas", "interpret")
 
 
 def _bucket_edges(n: int, n_buckets: int) -> np.ndarray:
@@ -132,21 +133,17 @@ def _jit_wef_ref(n_buckets: int):
 
 
 def _batched_entropy_columns(cc: ClassCodes, n_buckets: int, backend: str,
-                             interpret: Optional[bool]) -> Tuple[np.ndarray,
-                                                                 np.ndarray]:
+                             ) -> Tuple[np.ndarray, np.ndarray]:
     """(summary (N,4), bucket_H (N,n_buckets)) for one dtype class via the
     selected device path."""
     if backend == "jnp":
         summary, buck = _jit_wef_ref(n_buckets)(
             cc.codes, cc.n_valid, cc.n_rows, cc.n_cols, cc.lengths)
-    else:                                    # 'pallas'
-        import jax
+    else:                                    # 'pallas' | 'interpret'
         from repro.kernels.entropy_features import weighted_entropy_features
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         summary, buck = weighted_entropy_features(
             cc.codes, cc.n_valid, cc.n_rows, cc.n_cols, cc.lengths,
-            n_buckets=n_buckets, interpret=interpret)
+            n_buckets=n_buckets, interpret=backend == "interpret")
     return np.asarray(summary, np.float64), np.asarray(buck, np.float64)
 
 
@@ -156,7 +153,7 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
                            sizes: Optional[Sequence[int]] = None,
                            n_buckets: int = 5,
                            encoded: Optional[Dict[str, ClassCodes]] = None,
-                           interpret: Optional[bool] = None) -> np.ndarray:
+                           ) -> np.ndarray:
     """(N, F) feature matrix for N partitions in one pass.
 
     backend 'numpy' loops :func:`extract_features`; 'jnp' and 'pallas'
@@ -164,8 +161,9 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
     :func:`repro.data.tables.encode_dtype_classes`) and compute every
     entropy feature in a single batched device dispatch — the COMPREDICT
     hot path for ``CompressStage``/``StreamingEngine`` re-prediction.
-    'pallas' auto-selects interpret mode off-TPU unless ``interpret`` is
-    forced. All backends agree to ~1e-5 (tests/test_compredict_backends.py).
+    'pallas' always compiles the kernel for the device; 'interpret' runs
+    the same program in the Pallas interpreter. All backends agree to
+    ~1e-5 (tests/test_compredict_backends.py).
     """
     if backend not in FEATURE_BACKENDS:
         raise ValueError(f"backend must be one of {FEATURE_BACKENDS}, "
@@ -185,7 +183,7 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
         raise ValueError(kind)
     enc = encoded if encoded is not None else encode_dtype_classes(tables)
     per_class = {d: _batched_entropy_columns(
-        enc[d], n_buckets if kind == "bucketed" else 1, backend, interpret)
+        enc[d], n_buckets if kind == "bucketed" else 1, backend)
         for d in DTYPE_CLASSES}
     sizes_a = np.asarray(sizes, float)
     n_rows = np.maximum(np.array([t.num_rows for t in tables], float), 1.0)
@@ -295,7 +293,7 @@ class CompressionPredictor:
     (ratio, decompression sec/GB) from weighted-entropy features.
 
     ``feature_backend`` selects how :meth:`predict_matrix` extracts
-    features for a batch of partitions ('numpy' | 'jnp' | 'pallas', see
+    features for a batch of partitions (one of :data:`FEATURE_BACKENDS`, see
     :func:`extract_features_batch`); training always uses the NumPy path
     (label measurement dominates there anyway)."""
 
@@ -349,9 +347,8 @@ class CompressionPredictor:
         D = np.zeros((N, K))
         if N == 0:
             return R, D
-        backend = feature_backend or self.feature_backend
-        X = extract_features_batch(tables, layout, self.feature_kind,
-                                   backend, sizes=sizes)
+        X = self.features(tables, layout, sizes=sizes,
+                          feature_backend=feature_backend)
         for k, s in enumerate(schemes):
             if s == "none":
                 continue                       # (1, 0) by definition
@@ -360,3 +357,17 @@ class CompressionPredictor:
             D[:, k] = np.maximum(
                 self.models[(s, layout, "dspeed")].predict(X), 0.0)
         return R, D
+
+    def features(self, tables: Sequence[Table], layout: str, *,
+                 sizes: Optional[Sequence[int]] = None,
+                 feature_backend: Optional[str] = None,
+                 encoded: Optional[Dict[str, ClassCodes]] = None,
+                 ) -> np.ndarray:
+        """The (N, F) feature matrix :meth:`predict_matrix` feeds its
+        models: one :func:`extract_features_batch` pass with
+        ``feature_backend`` (else the constructor default); ``encoded``
+        reuses class codes from :func:`encode_dtype_classes`."""
+        return extract_features_batch(
+            tables, layout, self.feature_kind,
+            feature_backend or self.feature_backend, sizes=sizes,
+            encoded=encoded)

@@ -371,8 +371,8 @@ class PartitionIndex:
         ``(block, F)`` one-hot slab live at once); 'jnp' / 'pallas' /
         'interpret' dispatch the device kernel through
         :func:`repro.kernels.ops.fractional_overlap_matrix` (f32). With a
-        ``mesh``, row blocks are sharded across devices via the
-        ``repro.compat`` shard_map shim (single-device mesh falls back
+        ``mesh``, row blocks are sharded across devices via
+        ``repro.compat.shard_map`` (a single-device mesh falls back
         bit-identically).
         """
         if backend == "numpy":
@@ -786,11 +786,13 @@ def ordered_brute_force(parts: List[Partition],
 # ---------------------------------------------------------- sharded matrix
 def _overlap_matrix_sharded(codes: np.ndarray, sizes: np.ndarray,
                             spans: np.ndarray, mesh, impl: str = "jnp",
-                            axis: Optional[str] = None) -> np.ndarray:
+                            axis: Optional[str] = None):
     """Row-block-sharded overlap matrix: each device computes its row
     slab against the full (replicated) code set through the same kernel
-    dispatch, stitched with the ``repro.compat`` shard_map shim. A
-    single-device mesh degrades to the unsharded call bit-identically."""
+    dispatch, stitched with ``repro.compat.shard_map``. Returns the
+    row-sharded device array, padded to a multiple of the device count
+    in both dimensions. A single-device mesh degrades to the unsharded
+    call bit-identically."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -799,8 +801,7 @@ def _overlap_matrix_sharded(codes: np.ndarray, sizes: np.ndarray,
 
     axis = axis or mesh.axis_names[0]
     ndev = int(mesh.shape[axis])
-    N = codes.shape[0]
-    pad = (-N) % ndev
+    pad = (-codes.shape[0]) % ndev
     codes_p = np.pad(codes, ((0, pad), (0, 0)), constant_values=-1)
     spans_p = np.pad(spans, (0, pad))
 
@@ -813,6 +814,5 @@ def _overlap_matrix_sharded(codes: np.ndarray, sizes: np.ndarray,
         block, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(None, None), P(None), P(None)),
         out_specs=P(axis, None), check_vma=False)
-    out = fn(jnp.asarray(codes_p), jnp.asarray(spans_p),
-             jnp.asarray(codes_p), jnp.asarray(spans_p), jnp.asarray(sizes))
-    return np.asarray(out)[:N, :N]
+    return fn(jnp.asarray(codes_p), jnp.asarray(spans_p),
+              jnp.asarray(codes_p), jnp.asarray(spans_p), jnp.asarray(sizes))

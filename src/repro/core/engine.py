@@ -66,7 +66,7 @@ class ScopeConfig:
     partition_sample: Optional[float] = None  # MinHash-style code sampling
     # rate for the candidate graph (None = exact; see docs/engine.md)
     predictor: str = "truth"                 # 'truth' | fitted CompressionPredictor
-    feature_backend: str = "numpy"           # 'numpy' | 'jnp' | 'pallas'
+    feature_backend: str = "numpy"           # compredict.FEATURE_BACKENDS
     fixed_tier: Optional[int] = None         # e.g. 0 -> 'store on premium'
     # ---- serving SLA (soft constraints; see docs/engine.md) -------------
     sla_lambda: float = 0.0                  # objective = cost + lambda*penalty

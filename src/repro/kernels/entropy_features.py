@@ -4,17 +4,19 @@ The paper's feature pass is a full scan of each partition (its stated
 one-time compute cost, §V). Two device-resident primitives live here:
 
 * :func:`byte_entropy` — byte histogram + Shannon entropy of one payload.
-  On TPU the histogram is a one-hot matmul per VMEM block — (block, 256)
-  f32 one-hot against a ones vector rides the MXU — accumulating into a
-  (1, 256) scratch across the sequential grid axis.
+  The payload is laid out as (rows, 128) lanes; each row is compared
+  against a (256, 128) symbol iota and the matches accumulate
+  lane-parallel in VMEM scratch across the sequential grid axis.
 * :func:`weighted_entropy_features` — the batched COMPREDICT pipeline:
   per-dtype-class weighted entropy H(P,d), plain entropy, distinct
   fraction, and mean value length for N partitions at once, plus the
   bucketed successive-20%-of-rows entropy variant, with ragged-length and
-  pad masking. The grid is (partitions × code blocks); per block a
-  (n_buckets, block) × (block, vocab) one-hot matmul scatters counts into
-  a per-bucket histogram scratch, and features are reduced on the final
-  block. :func:`weighted_entropy_features_ref` is the ``jax.vmap``-based
+  pad masking. One linear scatter-add builds every partition's
+  (n_buckets, vocab) histogram (an M x V one-hot would cost ~1e14
+  compares at TPC-H SF 1); the grid kernel (partitions × vocabulary
+  tiles) then sums the separable entropy terms tile by tile and reduces
+  them on each partition's final tile.
+  :func:`weighted_entropy_features_ref` is the ``jax.vmap``-based
   pure-jnp oracle with identical semantics.
 
 Inputs for the batched form come from
@@ -36,106 +38,115 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(d_ref, hist_ref, ent_ref, hist_scr, *, block: int, n: int):
+_LANES = 128
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _byte_kernel(d_ref, hist_ref, ent_ref, acc_scr, *, rows: int, n: int):
+    """Grid (row block,), sequential. Each (1, 128) row of bytes is compared
+    against a (256, 128) symbol iota, so counts accumulate lane-parallel in
+    ``acc_scr`` and are lane-reduced once, on the final block."""
     bi = pl.program_id(0)
-    nb = pl.num_programs(0)
 
     @pl.when(bi == 0)
     def _init():
-        hist_scr[...] = jnp.zeros_like(hist_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    data = d_ref[...].astype(jnp.int32)            # (1, block)
-    pos = bi * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    valid = pos < n
-    onehot = (data[0][:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, 256), 1)).astype(jnp.float32)
-    onehot *= valid[0][:, None].astype(jnp.float32)
-    hist_scr[...] += onehot.sum(axis=0, keepdims=True)
+    sym = jax.lax.broadcasted_iota(jnp.int32, (256, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
-    @pl.when(bi == nb - 1)
+    def body(r, carry):
+        x = d_ref[pl.ds(r, 1), :]                              # (1, 128)
+        pos = (bi * rows + r) * _LANES + lane
+        x = jnp.where(pos < n, x, -1)                    # pads match no symbol
+        acc_scr[...] += (sym == x).astype(jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, rows, body, 0)
+
+    @pl.when(bi == pl.num_programs(0) - 1)
     def _finalize():
-        h = hist_scr[...]
+        h = acc_scr[...].sum(axis=1, keepdims=True)            # (256, 1)
         hist_ref[...] = h.astype(jnp.int32)
-        p = h / jnp.maximum(jnp.float32(n), 1.0)
-        ent = -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-30)),
-                                 0.0))
-        ent_ref[0, 0] = ent
+        p = h / jnp.float32(max(n, 1))
+        plogp = jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-30)), 0.0)
+        ent = -plogp.sum(axis=0, keepdims=True)                # (1, 1)
+        ent_ref[...] = jnp.broadcast_to(ent, ent_ref.shape)
 
 
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def byte_entropy(data, *, block: int = 8192, interpret: bool = False):
-    """data: (n,) uint8 -> (hist (256,) int32, entropy bits/byte scalar)."""
+    """data: (n,) uint8 -> (hist (256,) int32, entropy bits/byte scalar).
+
+    The payload is laid out as (rows, 128) int32 and swept in blocks of
+    ``block`` bytes (rounded to whole (8, 128) tiles)."""
     n = data.shape[0]
-    block = min(block, max(n, 1))
-    pad = (-n) % block
-    d = jnp.pad(data, (0, pad)).reshape(1, -1)
-    nb = d.shape[1] // block
-    kernel = functools.partial(_kernel, block=block, n=n)
+    rows = _round_up(max(min(block, max(n, 1)), 1), 8 * _LANES) // _LANES
+    n_pad = _round_up(max(n, 1), rows * _LANES)
+    d = jnp.pad(data.astype(jnp.int32), (0, n_pad - n)).reshape(-1, _LANES)
+    kernel = functools.partial(_byte_kernel, rows=rows, n=n)
     hist, ent = pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda bi: (0, bi))],
-        out_specs=[pl.BlockSpec((1, 256), lambda bi: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda bi: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 256), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, 256), jnp.float32)],
+        grid=(d.shape[0] // rows,),
+        in_specs=[pl.BlockSpec((rows, _LANES), lambda bi: (bi, 0))],
+        out_specs=[pl.BlockSpec((256, 1), lambda bi: (0, 0)),
+                   pl.BlockSpec((1, _LANES), lambda bi: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((256, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((1, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((256, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(d)
-    return hist[0], ent[0, 0]
+    return hist[:, 0], ent[0, 0]
 
 
 # ---------------------------------------------- batched weighted entropy
-def _wef_kernel(codes_ref, meta_ref, len_ref, sum_ref, buck_ref, hist_scr,
-                *, block: int, n_buckets: int, vpad: int):
-    """Grid (partition, code block). Scratch is the per-bucket histogram of
-    the current partition; features are reduced on its final block."""
-    bi = pl.program_id(1)
-    nb_blocks = pl.num_programs(1)
+_SUMMARY_ROW, _BUCKET_ROW = 0, 8        # output rows: 4 summary, nb bucket
 
-    @pl.when(bi == 0)
+
+def _wef_kernel(cnt_ref, len_ref, tot_ref, totb_ref, out_ref, acc_scr,
+                accb_scr):
+    """Grid (partition, vocabulary tile); the tile axis is sequential.
+
+    Every entropy term is separable per vocabulary entry, so each tile adds
+    its lane-wise partial sums into the scratch; the totals that normalize
+    p come in precomputed (``tot_ref`` / ``totb_ref``), never from the
+    histogram. Features are lane-reduced once, on the final tile."""
+    vi = pl.program_id(1)
+
+    @pl.when(vi == 0)
     def _init():
-        hist_scr[...] = jnp.zeros_like(hist_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        accb_scr[...] = jnp.zeros_like(accb_scr)
 
-    nv = meta_ref[0, 0]                            # values in this partition
-    nr = meta_ref[0, 1]                            # rows
-    nc = meta_ref[0, 2]                            # columns of this class
-    code = codes_ref[...].astype(jnp.int32)[0]                     # (block,)
-    pos = bi * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
-    valid = pos < nv                               # pad codes are -1 anyway
-    code_oh = ((code[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, vpad), 1)) & valid[:, None]).astype(jnp.float32)
-    if n_buckets == 1:
-        hist_scr[...] += code_oh.sum(axis=0, keepdims=True)
-    else:
-        # bucket b spans rows [floor(b*nr/nb), floor((b+1)*nr/nb)); the
-        # value at flat position p sits in row p // n_cols (row-major view)
-        row = pos // jnp.maximum(nc, 1)
-        b_iota = jax.lax.broadcasted_iota(
-            jnp.int32, (block, n_buckets - 1), 1) + 1
-        edges = (b_iota * nr) // n_buckets
-        bucket = (row[:, None] >= edges).sum(axis=1)               # (block,)
-        bucket_oh = (jax.lax.broadcasted_iota(
-            jnp.int32, (n_buckets, block), 0) == bucket[None, :]
-        ).astype(jnp.float32)
-        hist_scr[...] += jnp.dot(bucket_oh, code_oh,
-                                 preferred_element_type=jnp.float32)
+    cnt = cnt_ref[...].astype(jnp.float32)            # (nb, bv) per bucket
+    lens = len_ref[...]                               # (1, bv)
+    hist = cnt.sum(axis=0, keepdims=True)             # (1, bv)
+    p = hist / tot_ref[...]
+    plogp = jnp.where(p > 0, p * jnp.log(jnp.maximum(p, 1e-30)), 0.0)
+    acc_scr[0:1, :] += -lens * plogp                  # H(P,d)
+    acc_scr[1:2, :] += -plogp                         # plain H
+    acc_scr[2:3, :] += (hist > 0).astype(jnp.float32)  # distinct count
+    acc_scr[3:4, :] += lens * p                       # mean length
+    pb = cnt / totb_ref[...]
+    accb_scr[...] += -lens * jnp.where(
+        pb > 0, pb * jnp.log(jnp.maximum(pb, 1e-30)), 0.0)
 
-    @pl.when(bi == nb_blocks - 1)
+    @pl.when(vi == pl.num_programs(1) - 1)
     def _finalize():
-        lens = len_ref[...]                                      # (1, vpad)
-        hist_b = hist_scr[...]                                   # (nb, vpad)
-        hist = hist_b.sum(axis=0, keepdims=True)
-        total = jnp.maximum(nv.astype(jnp.float32), 1.0)
-        p = hist / total
-        plogp = jnp.where(p > 0, p * jnp.log(jnp.maximum(p, 1e-30)), 0.0)
-        sum_ref[0, 0] = -jnp.sum(lens * plogp)                   # H(P,d)
-        sum_ref[0, 1] = -jnp.sum(plogp)                          # plain H
-        sum_ref[0, 2] = jnp.sum((hist > 0).astype(jnp.float32)) / total
-        sum_ref[0, 3] = jnp.sum(lens * p)                        # mean len
-        tot_b = jnp.maximum(hist_b.sum(axis=1, keepdims=True), 1.0)
-        pb = hist_b / tot_b
-        plogpb = jnp.where(pb > 0, pb * jnp.log(jnp.maximum(pb, 1e-30)), 0.0)
-        buck_ref[...] = -(lens * plogpb).sum(axis=1)[None, :]
+        s = acc_scr[...].sum(axis=1, keepdims=True)   # (4, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(row == 2, s / tot_ref[...], s)  # distinct fraction
+        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[_SUMMARY_ROW:_SUMMARY_ROW + 4, :] = jnp.broadcast_to(
+            s, (4, _LANES))
+        nb = accb_scr.shape[0]
+        out_ref[_BUCKET_ROW:_BUCKET_ROW + nb, :] = jnp.broadcast_to(
+            accb_scr[...].sum(axis=1, keepdims=True), (nb, _LANES))
 
 
 def _as_batched_lengths(lengths, N: int) -> jnp.ndarray:
@@ -146,8 +157,46 @@ def _as_batched_lengths(lengths, N: int) -> jnp.ndarray:
     return lengths
 
 
+def _bucket_starts(n_rows, n_cols, n_buckets: int):
+    """First row-major value position of each bucket b >= 1: bucket b
+    spans rows [floor(b*nr/nb), floor((b+1)*nr/nb)), the last bucket
+    everything after its first row."""
+    nc = jnp.maximum(n_cols, 1)
+    return [((b * n_rows) // n_buckets) * nc for b in range(1, n_buckets)]
+
+
+def _bucket_totals(n_valid, n_rows, n_cols, n_buckets: int):
+    """(N, n_buckets) values per bucket, from the bucket edges alone (the
+    positions below ``n_valid`` that fall in each bucket)."""
+    edges = ([jnp.zeros_like(n_valid)]
+             + [jnp.minimum(s, n_valid)
+                for s in _bucket_starts(n_rows, n_cols, n_buckets)]
+             + [n_valid])
+    return jnp.stack([edges[b + 1] - edges[b] for b in range(n_buckets)],
+                     axis=1)
+
+
+def _histogram_index(codes, n_valid, n_rows, n_cols, n_buckets: int):
+    """(row, col) scatter indices of every code into the (N * n_buckets,
+    vocab) histogram: row ``partition * n_buckets + bucket``, col the code.
+    The two axes stay separate (a flattened int32 key would wrap once
+    N * n_buckets * vocab reaches 2**31); pads and positions past
+    ``n_valid`` get row ``N * n_buckets``, which the scatter drops."""
+    N, M = codes.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (N, M), 1)
+    part = jax.lax.broadcasted_iota(jnp.int32, (N, M), 0)
+    valid = (pos < n_valid[:, None]) & (codes >= 0)
+    bucket = jnp.zeros_like(pos)
+    for start in _bucket_starts(n_rows, n_cols, n_buckets):
+        bucket += (pos >= start[:, None]).astype(jnp.int32)
+    row = jnp.where(valid, part * n_buckets + bucket, N * n_buckets)
+    return row, jnp.where(valid, codes, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_buckets", "block",
+                                             "interpret"))
 def weighted_entropy_features(codes, n_valid, n_rows, n_cols, lengths, *,
-                              n_buckets: int = 1, block: int = 512,
+                              n_buckets: int = 1, block: int = 16384,
                               interpret: bool = False):
     """Batched per-partition weighted-entropy features, one device dispatch.
 
@@ -158,6 +207,11 @@ def weighted_entropy_features(codes, n_valid, n_rows, n_cols, lengths, *,
     width stays at the per-partition cardinality) or a (V,) vocabulary
     shared by every partition.
 
+    The per-bucket histograms are one linear scatter-add over all N*M
+    codes (no M x V one-hot); the Pallas kernel then reduces them in
+    ``block``-wide vocabulary tiles (rounded to 128 lanes), so VMEM holds a
+    few tiles whatever the vocabulary width.
+
     Returns ``(summary (N, 4) f32, bucket_H (N, n_buckets) f32)`` where the
     summary columns are [weighted entropy H(P,d), plain entropy, distinct
     fraction, mean value length] — natural-log, matching
@@ -166,33 +220,42 @@ def weighted_entropy_features(codes, n_valid, n_rows, n_cols, lengths, *,
     rows (``repro.core.compredict.bucketed_weighted_entropy``).
     """
     codes = jnp.asarray(codes, jnp.int32)
+    n_valid = jnp.asarray(n_valid, jnp.int32)
+    n_rows = jnp.asarray(n_rows, jnp.int32)
+    n_cols = jnp.asarray(n_cols, jnp.int32)
     N, M = codes.shape
-    block = min(block, max(M, 1))
-    pad = (-M) % block
-    if pad:
-        codes = jnp.pad(codes, ((0, 0), (0, pad)), constant_values=-1)
-    nb_blocks = codes.shape[1] // block
     lengths = _as_batched_lengths(lengths, N)
     V = lengths.shape[1]
-    vpad = -(-V // 128) * 128                      # lane-aligned vocabulary
-    lens = jnp.pad(lengths, ((0, 0), (0, vpad - V)))
-    meta = jnp.stack([jnp.asarray(n_valid), jnp.asarray(n_rows),
-                      jnp.asarray(n_cols)], axis=1).astype(jnp.int32)
-    kernel = functools.partial(_wef_kernel, block=block,
-                               n_buckets=n_buckets, vpad=vpad)
-    return pl.pallas_call(
-        kernel,
-        grid=(N, nb_blocks),
-        in_specs=[pl.BlockSpec((1, block), lambda i, bi: (i, bi)),
-                  pl.BlockSpec((1, 3), lambda i, bi: (i, 0)),
-                  pl.BlockSpec((1, vpad), lambda i, bi: (i, 0))],
-        out_specs=[pl.BlockSpec((1, 4), lambda i, bi: (i, 0)),
-                   pl.BlockSpec((1, n_buckets), lambda i, bi: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N, 4), jnp.float32),
-                   jax.ShapeDtypeStruct((N, n_buckets), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((n_buckets, vpad), jnp.float32)],
+    bv = min(_round_up(block, _LANES), _round_up(V, _LANES))
+    vpad = _round_up(V, bv)
+    nb = n_buckets
+
+    row, col = _histogram_index(codes, n_valid, n_rows, n_cols, nb)
+    counts = jnp.zeros((N * nb, vpad), jnp.int32).at[row, col].add(
+        1, mode="drop").reshape(N, nb, vpad)
+    lens = jnp.pad(lengths, ((0, 0), (0, vpad - V))).reshape(N, 1, vpad)
+    tot = jnp.maximum(n_valid, 1).astype(jnp.float32).reshape(N, 1, 1)
+    tot_b = jnp.maximum(_bucket_totals(n_valid, n_rows, n_cols, nb), 1
+                        ).astype(jnp.float32).reshape(N, nb, 1)
+    out_rows = _BUCKET_ROW + _round_up(nb, 8)
+    out = pl.pallas_call(
+        _wef_kernel,
+        grid=(N, vpad // bv),
+        in_specs=[pl.BlockSpec((None, nb, bv), lambda i, v: (i, 0, v)),
+                  pl.BlockSpec((None, 1, bv), lambda i, v: (i, 0, v)),
+                  pl.BlockSpec((None, 1, 1), lambda i, v: (i, 0, 0)),
+                  pl.BlockSpec((None, nb, 1), lambda i, v: (i, 0, 0))],
+        out_specs=pl.BlockSpec((None, out_rows, _LANES),
+                               lambda i, v: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, out_rows, _LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((4, bv), jnp.float32),
+                        pltpu.VMEM((nb, bv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(codes, meta, lens)
+    )(counts, lens, tot, tot_b)
+    return (out[:, _SUMMARY_ROW:_SUMMARY_ROW + 4, 0],
+            out[:, _BUCKET_ROW:_BUCKET_ROW + nb, 0])
 
 
 def weighted_entropy_features_ref(codes, n_valid, n_rows, n_cols, lengths, *,

@@ -88,12 +88,13 @@ def byte_entropy(data, *, impl: str = "auto"):
 
 
 def weighted_entropy_features(codes, n_valid, n_rows, n_cols, lengths, *,
-                              n_buckets: int = 1, block: int = 512,
+                              n_buckets: int = 1, block: int = 16384,
                               impl: str = "auto"):
     """Batched COMPREDICT feature primitive (see kernels/entropy_features.py).
 
-    'ref' is the vmapped-jnp path; 'pallas'/'interpret' run the batched
-    grid kernel. Returns (summary (N,4), bucket_H (N,n_buckets))."""
+    'ref' is the vmapped-jnp path; 'pallas'/'interpret' run the
+    scatter-add histogram plus the vocabulary-tiled reduction kernel.
+    Returns (summary (N,4), bucket_H (N,n_buckets))."""
     from repro.kernels import entropy_features as ek
     mode = _resolve(impl)
     if mode in ("pallas", "interpret"):
@@ -106,13 +107,14 @@ def weighted_entropy_features(codes, n_valid, n_rows, n_cols, lengths, *,
 
 # --------------------------------------------------------- overlap (DATAPART)
 def fractional_overlap_matrix(codes, sizes, spans, *, codes_b=None,
-                              spans_b=None, block_f: int = 512,
+                              spans_b=None, block_f: int = 2048,
                               impl: str = "auto"):
     """Batched G-PART fractional-overlap matrix (see kernels/overlap.py).
 
     'ref'/'jnp' is the vmapped-jnp oracle, 'numpy' the host fallback;
-    'pallas'/'interpret' run the blocked one-hot-matmul grid kernel.
-    Returns (NA, NB) f32."""
+    'pallas'/'interpret' run the tiled indicator-matmul grid kernel, which
+    needs ascending code rows and holds 2 bytes of indicator per (row,
+    file) for F files. Returns (NA, NB) f32."""
     from repro.kernels import overlap as ok
     mode = _resolve(impl)
     if mode == "jnp":        # engine backend names alias the jnp oracle

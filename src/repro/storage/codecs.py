@@ -15,12 +15,7 @@ import zlib
 from typing import Callable, Dict, List
 
 import numpy as np
-
-try:
-    import zstandard as zstd
-    _HAVE_ZSTD = True
-except ImportError:  # pragma: no cover
-    _HAVE_ZSTD = False
+import zstandard as zstd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,10 +66,8 @@ def default_codecs() -> List[Codec]:
         Codec("none", lambda b: b, lambda b: b),
         Codec("zlib-1", lambda b: zlib.compress(b, 1), zlib.decompress),
         Codec("zlib-6", lambda b: zlib.compress(b, 6), zlib.decompress),
-    ]
-    if _HAVE_ZSTD:
-        codecs += [_zstd_codec(3), _zstd_codec(19)]
-    codecs += [
+        _zstd_codec(3),
+        _zstd_codec(19),
         Codec("lzma-1", lambda b: lzma.compress(b, preset=1), lzma.decompress),
         Codec("quant8", _quant8_compress, _quant8_decompress, lossy=True),
     ]
@@ -94,11 +87,9 @@ DEFAULT_SCHEME_PREFERENCE = ("none", "zlib-1", "zstd-3", "zstd-19", "lzma-1")
 
 def available_schemes(
         preferred: tuple = DEFAULT_SCHEME_PREFERENCE) -> tuple:
-    """``preferred`` filtered down to codecs importable in this environment.
-
-    Lets pipeline defaults degrade gracefully when optional compressors
-    (zstandard) are absent instead of raising ``KeyError`` at config time.
-    """
+    """``preferred`` filtered down to the codecs in :func:`default_codecs`,
+    so a preference list naming codecs the registry lacks (snappy, lz4)
+    still yields a valid scheme tuple instead of a ``KeyError`` later."""
     names = {c.name for c in default_codecs()}
     return tuple(s for s in preferred if s in names)
 

@@ -16,7 +16,7 @@ import functools
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.compredict import (CompressionPredictor, _bucket_edges,
                                    bucketed_weighted_entropy,
@@ -25,7 +25,8 @@ from repro.core.compredict import (CompressionPredictor, _bucket_edges,
 from repro.data import tpch
 from repro.data.tables import DTYPE_CLASSES, Table, encode_dtype_classes
 from repro.kernels import ops
-from repro.kernels.entropy_features import (weighted_entropy_features,
+from repro.kernels.entropy_features import (_histogram_index,
+                                            weighted_entropy_features,
                                             weighted_entropy_features_ref)
 
 TOL = dict(rtol=1e-5, atol=1e-5)
@@ -61,7 +62,7 @@ def test_backends_agree_across_dtype_mixes(kind, mix):
     tabs = [_mk_table(n, 10 + n, **mix) for n in (7, 64, 129, 200, 1)]
     X_np = extract_features_batch(tabs, "col", kind, "numpy")
     X_jnp = extract_features_batch(tabs, "col", kind, "jnp")
-    X_pal = extract_features_batch(tabs, "col", kind, "pallas")
+    X_pal = extract_features_batch(tabs, "col", kind, "interpret")
     np.testing.assert_allclose(X_jnp, X_np, **TOL)
     np.testing.assert_allclose(X_pal, X_np, **TOL)
 
@@ -74,22 +75,23 @@ def test_backends_agree_on_tpch_query_samples():
     for kind in ("weighted_entropy", "bucketed"):
         X_np = extract_features_batch(tabs, "row", kind, "numpy")
         X_jnp = extract_features_batch(tabs, "row", kind, "jnp")
-        X_pal = extract_features_batch(tabs, "row", kind, "pallas")
+        X_pal = extract_features_batch(tabs, "row", kind, "interpret")
         np.testing.assert_allclose(X_jnp, X_np, **TOL)
         np.testing.assert_allclose(X_pal, X_np, **TOL)
 
 
 @pytest.mark.parametrize("n,block", [
-    (37, 64),       # n < block: block clamps, no pad
-    (128, 64),      # n % block == 0: empty-pad boundary
-    (130, 64),      # 2 bytes spill into a heavily padded final block
-    (1, 8),         # single value
+    (37, 128),      # vocabulary in three lane tiles, the last one padded
+    (128, 256),     # two tiles, the second mostly padding
+    (130, 384),     # one tile covers the whole padded vocabulary
+    (1, 100),       # single value; block rounds up to one 128-lane tile
 ])
 def test_kernel_vs_ref_pad_boundaries(n, block):
     """Pallas grid kernel (interpret) vs the vmapped-jnp oracle at ragged
-    lengths straddling block boundaries; pads must never leak."""
+    lengths, with vocabulary tiles straddling the padded width; pads must
+    never leak."""
     rng = np.random.default_rng(n)
-    N, V, nb = 3, 23, 5
+    N, V, nb = 3, 300, 5
     n_cols = np.array([2, 1, 3], np.int32)
     n_valid = np.minimum(n, np.array([n, max(n - 5, 1), n], np.int32))
     n_valid = (n_valid // n_cols) * n_cols          # whole rows
@@ -109,6 +111,29 @@ def test_kernel_vs_ref_pad_boundaries(n, block):
     np.testing.assert_allclose(np.asarray(b_pal), np.asarray(b_ref), **TOL)
 
 
+def test_histogram_index_does_not_wrap_past_int32():
+    """95 partitions x 5 buckets over the 4.56M-entry SF 1 float vocabulary
+    put N * nb * vocab past 2**31: a flattened int32 key would wrap to a
+    negative index and count into the wrong cells. The kernel's 2-D
+    (row, col) index keeps both axes in range."""
+    N, nb, M, V = 95, 5, 10, 4_561_471
+    n_valid = np.full(N, 8, np.int32)
+    n_cols = np.full(N, 2, np.int32)
+    n_rows = n_valid // n_cols
+    codes = np.full((N, M), -1, np.int32)
+    codes[:, :8] = V - 1 - np.arange(8, dtype=np.int32)
+    assert N * nb * V >= 2**31
+    row, col = (np.asarray(a) for a in _histogram_index(
+        codes, n_valid, n_rows, n_cols, nb))
+    # 4 rows of 2 values; bucket b holds rows [floor(b*4/5), floor((b+1)*4/5))
+    bucket = np.array([1, 1, 2, 2, 3, 3, 4, 4])
+    np.testing.assert_array_equal(
+        row[:, :8], np.arange(N)[:, None] * nb + bucket[None, :])
+    np.testing.assert_array_equal(row[:, 8:], N * nb)    # pads are dropped
+    np.testing.assert_array_equal(col[:, :8], codes[:, :8])
+    assert row.min() >= 0 and col.min() >= 0 and col.max() < V
+
+
 def test_ops_dispatch_ref_equals_interpret():
     codes = np.array([[0, 1, 1, 2, -1, -1]], np.int32)
     args = (codes, np.array([4]), np.array([2]), np.array([2]),
@@ -123,7 +148,7 @@ def test_ops_dispatch_ref_equals_interpret():
 def test_single_value_payload_is_zero_entropy():
     """Constant columns carry exactly 0 nats in every backend and bucket."""
     tabs = [_mk_table(50, 1, constant=True), _mk_table(3, 2, constant=True)]
-    for backend in ("numpy", "jnp", "pallas"):
+    for backend in ("numpy", "jnp", "interpret"):
         X = extract_features_batch(tabs, "col", "bucketed", backend)
         base, blk = 3, 5
         for ci in range(len(DTYPE_CLASSES)):
@@ -138,13 +163,13 @@ def test_all_backends_handle_zero_rows():
     divide by zero here, breaking backend invariance."""
     tabs = [_mk_table(0, 5), _mk_table(10, 6)]
     outs = {}
-    for backend in ("numpy", "jnp", "pallas"):
+    for backend in ("numpy", "jnp", "interpret"):
         X = extract_features_batch(tabs, "col", "weighted_entropy", backend)
         assert np.isfinite(X).all(), backend
         np.testing.assert_allclose(X[0, 3:], [0, 0, 0, 0, 1] * 3, atol=1e-6)
         outs[backend] = X
     np.testing.assert_allclose(outs["jnp"], outs["numpy"], **TOL)
-    np.testing.assert_allclose(outs["pallas"], outs["numpy"], **TOL)
+    np.testing.assert_allclose(outs["interpret"], outs["numpy"], **TOL)
 
 
 def test_n_buckets_is_honored_by_every_backend():
@@ -152,11 +177,11 @@ def test_n_buckets_is_honored_by_every_backend():
     and the empty-batch width formula must match the non-empty one."""
     tabs = [_mk_table(17, 8), _mk_table(40, 9)]
     outs = {b: extract_features_batch(tabs, "col", "bucketed", b, n_buckets=3)
-            for b in ("numpy", "jnp", "pallas")}
+            for b in ("numpy", "jnp", "interpret")}
     for b, X in outs.items():
         assert X.shape == (2, 18 + 3 * 3), b
     np.testing.assert_allclose(outs["jnp"], outs["numpy"], **TOL)
-    np.testing.assert_allclose(outs["pallas"], outs["numpy"], **TOL)
+    np.testing.assert_allclose(outs["interpret"], outs["numpy"], **TOL)
     empty = extract_features_batch([], "col", "bucketed", "numpy",
                                    n_buckets=3)
     assert empty.shape == (0, outs["numpy"].shape[1])
@@ -231,8 +256,8 @@ def test_predict_matrix_backend_invariance(seed):
     subset = tabs[seed % len(tabs):]
     out = {b: pred.predict_matrix(subset, ["none", scheme], "col",
                                   feature_backend=b)
-           for b in ("numpy", "jnp", "pallas")}
-    for b in ("jnp", "pallas"):
+           for b in ("numpy", "jnp", "interpret")}
+    for b in ("jnp", "interpret"):
         np.testing.assert_allclose(out[b][0], out["numpy"][0], rtol=1e-4,
                                    atol=1e-6)
         np.testing.assert_allclose(out[b][1], out["numpy"][1], rtol=1e-4,

@@ -2,7 +2,7 @@
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.costs import (Weights, azure_table, cost_tensor,
                               latency_feasible, tpch_capacity_table)

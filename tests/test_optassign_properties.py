@@ -1,10 +1,8 @@
 """Property-based invariants for the capacitated OPTASSIGN solvers.
 
-Runs under real ``hypothesis`` when installed (the CI ``properties`` job)
-and under the deterministic ``tests/_hypothesis_compat.py`` enumeration
-otherwise. Strategies draw a SEED, not arrays: every example uses the same
-(N, L, K) shapes so the jitted Lagrangian scan compiles once, and the
-seeded ``default_rng`` varies the values.
+Runs under ``hypothesis``. Strategies draw a SEED, not arrays: every
+example uses the same (N, L, K) shapes so the jitted Lagrangian scan
+compiles once, and the seeded ``default_rng`` varies the values.
 
 Invariants:
 
@@ -19,13 +17,8 @@ Invariants:
   by hand.
 """
 
-import sys
-from pathlib import Path
-
 import numpy as np
-
-sys.path.insert(0, str(Path(__file__).parent))
-from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core.costs import (Weights, azure_table, cost_tensor,
                               sla_penalty_tensor)

@@ -1,8 +1,8 @@
 """Overlap-kernel property suite: the fractional-overlap matrix backends
 (numpy / jnp-ref / pallas-interpret) and the PartitionIndex CSR core.
 
-Each property runs over seeded random instances via the hypothesis shim
-(`_hypothesis_compat`): symmetry, [0, 1] range, exact zero for disjoint
+Each property runs over seeded random instances drawn by hypothesis:
+symmetry, [0, 1] range, exact zero for disjoint
 code sets (the PYTHONHASHSEED bug class from PR 2 — no fp residue may link
 disjoint partitions), permutation invariance, cross-backend differentials
 to 1e-5, and lossless ``Partition`` <-> ``PartitionIndex`` round-trip.
@@ -10,7 +10,7 @@ to 1e-5, and lossless ``Partition`` <-> ``PartitionIndex`` round-trip.
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import datapart as dp
 from repro.kernels import ops

@@ -79,8 +79,8 @@ def test_paper_variant_grid(pipeline_inputs):
 
 
 def test_feature_backend_parity_end_to_end():
-    """CompressStage with feature_backend='pallas' (interpret on CPU) and
-    'jnp' must produce the *identical* PlacementPlan — same tiers, same
+    """CompressStage with feature_backend='interpret' (the Pallas program
+    in the interpreter) and 'jnp' must produce the *identical* PlacementPlan — same tiers, same
     schemes — as the NumPy feature loop on a seeded TPC-H-style workload."""
     db = tpch.generate(scale_rows=900, seed=2)
     queries = tpch.generate_queries(db, n_per_template=2, seed=3)
@@ -94,10 +94,10 @@ def test_feature_backend_parity_end_to_end():
     base_cfg = ScopeConfig(schemes=schemes, predictor=pred,
                            tier_whitelist=(0, 1, 2))
     plans = {}
-    for backend in ("numpy", "jnp", "pallas"):
+    for backend in ("numpy", "jnp", "interpret"):
         cfg = dataclasses.replace(base_cfg, feature_backend=backend)
         plans[backend] = PlacementEngine(table, cfg).run(parts, file_rows)
-    for backend in ("jnp", "pallas"):
+    for backend in ("jnp", "interpret"):
         np.testing.assert_array_equal(plans[backend].assignment.tier,
                                       plans["numpy"].assignment.tier)
         np.testing.assert_array_equal(plans[backend].assignment.scheme,

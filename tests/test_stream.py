@@ -12,7 +12,7 @@ The contract under test (docs/engine.md "Streaming ingestion"):
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import datapart as dp
 from repro.core.stream import StreamingPartitioner

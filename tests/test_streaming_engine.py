@@ -189,11 +189,11 @@ def _compredict_stream_fixture():
 
 
 def test_streaming_feature_backend_parity():
-    """Streaming re-prediction through compredict_rd_fn: the Pallas and
-    NumPy feature backends yield the identical per-batch placement."""
+    """Streaming re-prediction through compredict_rd_fn: the interpreted
+    Pallas and NumPy feature backends yield the identical per-batch placement."""
     pred, file_rows, sizes, schemes, batches = _compredict_stream_fixture()
     migs = {}
-    for backend in ("numpy", "pallas"):
+    for backend in ("numpy", "interpret"):
         cfg = ScopeConfig(months=1.0, schemes=schemes)
         eng = StreamingEngine(
             azure_table(), cfg, sizes, s_thresh=5.0,
@@ -201,7 +201,7 @@ def test_streaming_feature_backend_parity():
                                    feature_backend=backend))
         migs[backend] = [eng.ingest_and_reoptimize(b, months=1.0)
                         for b in batches]
-    for m_np, m_pal in zip(migs["numpy"], migs["pallas"]):
+    for m_np, m_pal in zip(migs["numpy"], migs["interpret"]):
         np.testing.assert_array_equal(m_pal.plan.assignment.tier,
                                       m_np.plan.assignment.tier)
         np.testing.assert_array_equal(m_pal.plan.assignment.scheme,

@@ -1,5 +1,5 @@
-"""Benchmark entry point — one module per paper table/figure + framework
-micro/roofline benches. Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark entry point — one module per paper table/figure + kernel
+microbenches. Prints ``name,us_per_call,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run [--only tableII,fig7,...]
 """
@@ -26,7 +26,6 @@ MODULES = [
     ("forecast", "benchmarks.bench_forecast"),
     ("sla", "benchmarks.bench_sla"),
     ("kernels", "benchmarks.bench_kernels"),
-    ("roofline", "benchmarks.bench_roofline"),
 ]
 
 

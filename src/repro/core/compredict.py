@@ -20,7 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import ml
+from repro.core import ml, tracing
 from repro.data.tables import (ClassCodes, Table, dtype_class,
                                encode_dtype_classes, DTYPE_CLASSES)
 from repro.storage.codecs import Codec, default_codecs, measure
@@ -181,10 +181,14 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
                          for t, s in zip(tables, sizes)])
     if kind not in ("weighted_entropy", "bucketed"):
         raise ValueError(kind)
-    enc = encoded if encoded is not None else encode_dtype_classes(tables)
-    per_class = {d: _batched_entropy_columns(
-        enc[d], n_buckets if kind == "bucketed" else 1, backend)
-        for d in DTYPE_CLASSES}
+    enc = encoded
+    if enc is None:
+        with tracing.span("features.encode"):
+            enc = encode_dtype_classes(tables)
+    with tracing.span("features.entropy"):
+        per_class = {d: _batched_entropy_columns(
+            enc[d], n_buckets if kind == "bucketed" else 1, backend)
+            for d in DTYPE_CLASSES}
     sizes_a = np.asarray(sizes, float)
     n_rows = np.maximum(np.array([t.num_rows for t in tables], float), 1.0)
     cols = [np.log1p(sizes_a), np.log1p(n_rows), sizes_a / n_rows]

@@ -31,6 +31,8 @@ from typing import (Dict, FrozenSet, Iterable, List, Optional, Sequence,
 
 import numpy as np
 
+from repro.core import tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class Partition:
@@ -621,37 +623,38 @@ def g_part(parts: List[Partition], s_thresh: float, rho_c: float = 4.0,
         return g_part_ref(parts, s_thresh, rho_c, rho_c_abs)
     if not parts:
         return []
-    index = PartitionIndex.from_partitions(parts)
-    if sample is not None or backend == "numpy":
-        pi, pj = index.candidate_pairs(sample=sample, seed=sample_seed,
-                                       max_degree=max_degree)
-    else:
-        w_mat = index.overlap_matrix(backend=backend, mesh=mesh)
-        pi, pj = np.nonzero(np.triu(w_mat, 1) > 0.0)
-    spans = index.span()
-    inter = index.pair_overlap_spans(pi, pj)
-    w = _pair_weights(spans[pi], spans[pj], inter)
-    ok = (w > 0.0) & _feasible_mask(index.rho[pi], index.rho[pj],
-                                    rho_c, rho_c_abs)
+    with tracing.span("gpart"):
+        index = PartitionIndex.from_partitions(parts)
+        if sample is not None or backend == "numpy":
+            pi, pj = index.candidate_pairs(sample=sample, seed=sample_seed,
+                                           max_degree=max_degree)
+        else:
+            w_mat = index.overlap_matrix(backend=backend, mesh=mesh)
+            pi, pj = np.nonzero(np.triu(w_mat, 1) > 0.0)
+        spans = index.span()
+        inter = index.pair_overlap_spans(pi, pj)
+        w = _pair_weights(spans[pi], spans[pj], inter)
+        ok = (w > 0.0) & _feasible_mask(index.rho[pi], index.rho[pj],
+                                        rho_c, rho_c_abs)
 
-    store = _NodeStore(index.interner)
-    for i in range(index.n):
-        store.add(i, index.row(i), float(index.rho[i]),
-                  span=float(spans[i]))
-    neighbors: Dict[int, Set[int]] = {i: set() for i in range(index.n)}
-    for a, b in zip(pi, pj):           # the w>0 graph, kept for merges
-        neighbors[int(a)].add(int(b))
-        neighbors[int(b)].add(int(a))
-    heap = [(-float(w[t]), int(pi[t]), int(pj[t]))
-            for t in np.flatnonzero(ok)]
-    heapq.heapify(heap)
-    _merge_loop(store, heap, index.n, s_thresh, rho_c, rho_c_abs,
-                neighbors=neighbors)
-    fs = parts[0].sizes
-    ids = index.interner.file_ids
-    return [Partition(frozenset(ids[c] for c in codes),
-                      store.rho[nid], fs)
-            for nid, codes in store.codes.items()]
+        store = _NodeStore(index.interner)
+        for i in range(index.n):
+            store.add(i, index.row(i), float(index.rho[i]),
+                      span=float(spans[i]))
+        neighbors: Dict[int, Set[int]] = {i: set() for i in range(index.n)}
+        for a, b in zip(pi, pj):           # the w>0 graph, kept for merges
+            neighbors[int(a)].add(int(b))
+            neighbors[int(b)].add(int(a))
+        heap = [(-float(w[t]), int(pi[t]), int(pj[t]))
+                for t in np.flatnonzero(ok)]
+        heapq.heapify(heap)
+        _merge_loop(store, heap, index.n, s_thresh, rho_c, rho_c_abs,
+                    neighbors=neighbors)
+        fs = parts[0].sizes
+        ids = index.interner.file_ids
+        return [Partition(frozenset(ids[c] for c in codes),
+                          store.rho[nid], fs)
+                for nid, codes in store.codes.items()]
 
 
 def merge_all(parts: List[Partition]) -> List[Partition]:

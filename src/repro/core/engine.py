@@ -28,7 +28,7 @@ from typing import (Callable, Dict, FrozenSet, List, Optional, Sequence,
 
 import numpy as np
 
-from repro.core import datapart
+from repro.core import datapart, tracing
 from repro.core.cache import (CacheConfig, cache_access_adjustment,
                               cache_cents, forecast_admission,
                               served_latency_terms, weighted_p99_ms)
@@ -414,32 +414,35 @@ class PartitionStage:
     def __call__(self, parts: List[datapart.Partition],
                  file_rows: Dict[str, Tuple[Table, np.ndarray]],
                  ) -> PartitionedData:
-        cfg = self.cfg
-        if cfg.use_partitioning:
-            med = float(np.median([p.span for p in parts])) if parts else 0.0
-            mesh = None
-            if cfg.partition_backend in ("jnp", "pallas"):
-                from repro.distributed import ctx
-                mesh = ctx.mesh()
-            merged = datapart.g_part(parts, s_thresh=cfg.s_thresh_mult * med,
-                                     rho_c=cfg.rho_c, rho_c_abs=cfg.rho_c_abs,
-                                     backend=cfg.partition_backend,
-                                     sample=cfg.partition_sample, mesh=mesh)
-        else:
-            # paper's non-partitioned baselines treat each DATASET (table) as
-            # one partition: every access scans its whole table
-            by_table: Dict[str, List[datapart.Partition]] = {}
-            for p in parts:
-                tname = sorted(p.files)[0].split("/")[0]
-                by_table.setdefault(tname, []).append(p)
-            merged = []
-            for group in by_table.values():
-                merged.extend(datapart.merge_all(group))
-        tables = self._partition_tables(merged, file_rows)
-        raw_bytes = [t.serialize(cfg.layout) for t in tables]
-        spans_gb = np.array([len(b) / 1e9 for b in raw_bytes])
-        rho = np.array([p.rho for p in merged])
-        return PartitionedData(merged, tables, raw_bytes, spans_gb, rho)
+        with tracing.span("partition"):
+            cfg = self.cfg
+            if cfg.use_partitioning:
+                med = (float(np.median([p.span for p in parts]))
+                       if parts else 0.0)
+                mesh = None
+                if cfg.partition_backend in ("jnp", "pallas"):
+                    from repro.distributed import ctx
+                    mesh = ctx.mesh()
+                merged = datapart.g_part(
+                    parts, s_thresh=cfg.s_thresh_mult * med, rho_c=cfg.rho_c,
+                    rho_c_abs=cfg.rho_c_abs, backend=cfg.partition_backend,
+                    sample=cfg.partition_sample, mesh=mesh)
+            else:
+                # paper's non-partitioned baselines treat each DATASET
+                # (table) as one partition: every access scans its table
+                by_table: Dict[str, List[datapart.Partition]] = {}
+                for p in parts:
+                    tname = sorted(p.files)[0].split("/")[0]
+                    by_table.setdefault(tname, []).append(p)
+                merged = []
+                for group in by_table.values():
+                    merged.extend(datapart.merge_all(group))
+            with tracing.span("partition.materialize"):
+                tables = self._partition_tables(merged, file_rows)
+                raw_bytes = [t.serialize(cfg.layout) for t in tables]
+            spans_gb = np.array([len(b) / 1e9 for b in raw_bytes])
+            rho = np.array([p.rho for p in merged])
+            return PartitionedData(merged, tables, raw_bytes, spans_gb, rho)
 
 
 class CompressStage:
@@ -457,34 +460,37 @@ class CompressStage:
 
     def __call__(self, data: PartitionedData, table: CostTable,
                  ) -> PlacementProblem:
-        cfg = self.cfg
-        N = len(data.partitions)
-        schemes = list(cfg.schemes) if cfg.use_compression else ["none"]
-        K = len(schemes)
-        R = np.ones((N, K))
-        D = np.zeros((N, K))
-        if cfg.use_compression:
-            if cfg.predictor == "truth":
-                for i, b in enumerate(data.raw_bytes):
-                    for k, s in enumerate(schemes):
-                        if s == "none":
-                            continue
-                        m = measure(codec_by_name(s), b)
-                        R[i, k] = m.ratio
-                        D[i, k] = m.decompress_sec_per_gb * (len(b) / 1e9)
-            else:
-                pred = cfg.predictor  # fitted CompressionPredictor instance
-                Rm, Dm = pred.predict_matrix(
-                    data.tables, schemes, cfg.layout,
-                    sizes=[len(b) for b in data.raw_bytes],
-                    feature_backend=cfg.feature_backend)
-                R = Rm
-                D = Dm * data.spans_gb[:, None]  # sec/GB -> sec per partition
-        return PlacementProblem(
-            spans_gb=data.spans_gb, rho=data.rho,
-            current_tier=np.full(N, -1), R=R, D=D, schemes=schemes,
-            table=table, cfg=cfg, partitions=data.partitions,
-            raw_bytes=data.raw_bytes)
+        with tracing.span("compress"):
+            cfg = self.cfg
+            N = len(data.partitions)
+            schemes = list(cfg.schemes) if cfg.use_compression else ["none"]
+            K = len(schemes)
+            R = np.ones((N, K))
+            D = np.zeros((N, K))
+            if cfg.use_compression:
+                if cfg.predictor == "truth":
+                    for i, b in enumerate(data.raw_bytes):
+                        for k, s in enumerate(schemes):
+                            if s == "none":
+                                continue
+                            m = measure(codec_by_name(s), b)
+                            R[i, k] = m.ratio
+                            D[i, k] = (m.decompress_sec_per_gb
+                                       * (len(b) / 1e9))
+                else:
+                    pred = cfg.predictor  # a fitted CompressionPredictor
+                    Rm, Dm = pred.predict_matrix(
+                        data.tables, schemes, cfg.layout,
+                        sizes=[len(b) for b in data.raw_bytes],
+                        feature_backend=cfg.feature_backend)
+                    R = Rm
+                    # sec/GB -> sec per partition
+                    D = Dm * data.spans_gb[:, None]
+            return PlacementProblem(
+                spans_gb=data.spans_gb, rho=data.rho,
+                current_tier=np.full(N, -1), R=R, D=D, schemes=schemes,
+                table=table, cfg=cfg, partitions=data.partitions,
+                raw_bytes=data.raw_bytes)
 
 
 class AssignStage:
@@ -609,14 +615,15 @@ class AssignStage:
     def __call__(self, problem: PlacementProblem,
                  extra_cost: Optional[np.ndarray] = None,
                  locked_scheme: Optional[np.ndarray] = None) -> Assignment:
-        cost, feas, stored, cap, tg, gcap = self.solver_inputs(
-            problem, extra_cost, locked_scheme)
-        if cap is None and tg is None:
-            return greedy_assign(cost, feas)
-        if cap is None:
-            cap = np.full(self.table.num_tiers, np.inf)
-        return capacitated_assign(cost, feas, stored, cap, tier_groups=tg,
-                                  group_capacity_gb=gcap)
+        with tracing.span("assign"):
+            cost, feas, stored, cap, tg, gcap = self.solver_inputs(
+                problem, extra_cost, locked_scheme)
+            if cap is None and tg is None:
+                return greedy_assign(cost, feas)
+            if cap is None:
+                cap = np.full(self.table.num_tiers, np.inf)
+            return capacitated_assign(cost, feas, stored, cap,
+                                      tier_groups=tg, group_capacity_gb=gcap)
 
 
 class BillingStage:
@@ -707,12 +714,14 @@ class PlacementEngine:
 
     def solve(self, problem: PlacementProblem) -> PlacementPlan:
         assignment = self.assign(problem)
-        report = self.billing(problem, assignment)
+        with tracing.span("billing"):
+            report = self.billing(problem, assignment)
         return PlacementPlan(problem, assignment, report)
 
     def run(self, parts: List[datapart.Partition],
             file_rows: Dict[str, Tuple[Table, np.ndarray]]) -> PlacementPlan:
-        return self.solve(self.build_problem(parts, file_rows))
+        with tracing.span("plan"):
+            return self.solve(self.build_problem(parts, file_rows))
 
     # ----------------------------------------------------------- replicas
     def plan_replicas(self, plan: PlacementPlan,

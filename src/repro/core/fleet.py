@@ -25,6 +25,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.engine import (MigrationPlan, PlacementEngine, PlacementPlan,
                                PlacementProblem)
 from repro.core.optassign import (FleetAssignment, capacitated_assign_batch,
@@ -119,41 +120,46 @@ class FleetEngine:
         neither per-tier caps, provider caps, nor shared rows constrain
         anything, the capacitated batch otherwise.
         """
-        T = len(problems)
-        extra_costs = list(extra_costs) if extra_costs is not None \
-            else [None] * T
-        locked_schemes = list(locked_schemes) if locked_schemes is not None \
-            else [None] * T
-        ins = [self.engine.assign.solver_inputs(p, ec, lk)
-               for p, ec, lk in zip(problems, extra_costs, locked_schemes)]
-        costs = [i[0] for i in ins]
-        feases = [i[1] for i in ins]
-        if T == 0 or (ins[0][3] is None and ins[0][4] is None
-                      and self.shared_tier_groups is None):
-            assignments = greedy_assign_batch(costs, feases)
-            feasible = all(a.feasible for a in assignments)
-            cost = (float(sum(a.cost for a in assignments)) if feasible
-                    else float("inf"))
-            return FleetAssignment(assignments, cost, feasible, None)
-        L = self.table.num_tiers
-        caps = [i[3] if i[3] is not None else np.full(L, np.inf)
-                for i in ins]
-        tg = ins[0][4]
-        gcaps = [i[5] for i in ins] if tg is not None else None
-        return capacitated_assign_batch(
-            costs, feases, [i[2] for i in ins], caps,
-            tier_groups=tg, group_capacity_gb=gcaps,
-            shared_tier_groups=self.shared_tier_groups,
-            shared_capacity_gb=self.shared_capacity_gb,
-            mesh=self.mesh)
+        with tracing.span("assign"):
+            T = len(problems)
+            extra_costs = (list(extra_costs) if extra_costs is not None
+                           else [None] * T)
+            locked_schemes = (list(locked_schemes)
+                              if locked_schemes is not None else [None] * T)
+            with tracing.span("assign.inputs"):
+                ins = [self.engine.assign.solver_inputs(p, ec, lk)
+                       for p, ec, lk in zip(problems, extra_costs,
+                                            locked_schemes)]
+            costs = [i[0] for i in ins]
+            feases = [i[1] for i in ins]
+            if T == 0 or (ins[0][3] is None and ins[0][4] is None
+                          and self.shared_tier_groups is None):
+                assignments = greedy_assign_batch(costs, feases)
+                feasible = all(a.feasible for a in assignments)
+                cost = (float(sum(a.cost for a in assignments)) if feasible
+                        else float("inf"))
+                return FleetAssignment(assignments, cost, feasible, None)
+            L = self.table.num_tiers
+            caps = [i[3] if i[3] is not None else np.full(L, np.inf)
+                    for i in ins]
+            tg = ins[0][4]
+            gcaps = [i[5] for i in ins] if tg is not None else None
+            return capacitated_assign_batch(
+                costs, feases, [i[2] for i in ins], caps,
+                tier_groups=tg, group_capacity_gb=gcaps,
+                shared_tier_groups=self.shared_tier_groups,
+                shared_capacity_gb=self.shared_capacity_gb,
+                mesh=self.mesh)
 
     # -------------------------------------------------------------- solve
     def solve(self, problems: Sequence[PlacementProblem]) -> FleetPlan:
         """Assignment + billing for every tenant, one assignment dispatch."""
-        fleet = self.assign_batch(problems)
-        plans = [PlacementPlan(p, a, self.engine.billing(p, a))
-                 for p, a in zip(problems, fleet.assignments)]
-        return FleetPlan(plans, fleet)
+        with tracing.span("fleet.plan"):
+            fleet = self.assign_batch(problems)
+            with tracing.span("billing"):
+                plans = [PlacementPlan(p, a, self.engine.billing(p, a))
+                         for p, a in zip(problems, fleet.assignments)]
+            return FleetPlan(plans, fleet)
 
     # --------------------------------------------------------- reoptimize
     def reoptimize(self, plans: Sequence[PlacementPlan], new_rhos: Sequence,

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
+
 BIG = 1e18
 
 
@@ -1200,75 +1202,77 @@ def capacitated_assign_batch(
                       if fleet_cells.size else 0.0)
         else:
             sstep0 = 0.0
-        cells = np.asarray(_run_fleet_scan(mesh, masked_b, stored_b, cap_b,
-                                           gcap_b, g_of_t,
-                                           np.asarray(sg, np.int32), scap,
-                                           sstep0, step0_b, iters))
+        with tracing.span("assign.scan"):
+            cells = np.asarray(_run_fleet_scan(mesh, masked_b, stored_b, cap_b,
+                                               gcap_b, g_of_t,
+                                               np.asarray(sg, np.int32), scap,
+                                               sstep0, step0_b, iters))
 
-        if not has_shared:
-            done.update(_batch_candidate_finish(
-                solve_idx, cells, masked_b, stored_b, maskeds, storeds, As,
-                cap_alls, finite_alls, Ns, K, max_candidates))
-        else:
-            joint = _dedupe_candidates(
-                (cells[r].ravel() for r in range(cells.shape[0])),
-                max_candidates)
-            best_score = float("inf")
-            best_state = None
-            fallback = None
-            for cand in joint:
-                grid = cand.reshape(Tp, n_max)
-                tiers = [grid[j, :Ns[t]] // K
-                         for j, t in enumerate(solve_idx)]
-                schemes = [grid[j, :Ns[t]] % K
-                           for j, t in enumerate(solve_idx)]
-                if fallback is None:
-                    fallback = ([x.copy() for x in tiers],
-                                [x.copy() for x in schemes])
-                m_l = [maskeds[t] for t in solve_idx]
-                s_l = [storeds[t] for t in solve_idx]
-                A_l = [As[t] for t in solve_idx]
-                c_l = [cap_alls[t] for t in solve_idx]
-                f_l = [finite_alls[t] for t in solve_idx]
-                uses = []
-                dead = False
-                for j in range(Tp):
-                    use = _repair_vec(tiers[j], schemes[j], m_l[j], s_l[j],
-                                      A_l[j], c_l[j], f_l[j])
-                    if use is None:
-                        dead = True
-                        break
-                    uses.append(use)
-                if dead:
-                    continue
-                su = _fleet_repair_shared(tiers, schemes, uses, m_l, s_l,
-                                          A_l, c_l, f_l, A_sh, scap,
-                                          finite_sh)
-                if su is None:
-                    continue
-                _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l, f_l,
-                              A_sh, scap, finite_sh, su)
-                score = sum(
-                    float(m_l[j][np.arange(Ns[t]), tiers[j],
-                                 schemes[j]].sum())
-                    for j, t in enumerate(solve_idx))
-                if score < BIG and score < best_score:
-                    best_score = score
-                    best_state = ([x.copy() for x in tiers],
-                                  [x.copy() for x in schemes])
-            if best_state is not None:
-                tiers, schemes = best_state
-                for j, t in enumerate(solve_idx):
-                    total = float(maskeds[t][np.arange(Ns[t]), tiers[j],
-                                             schemes[j]].sum())
-                    done[t] = Assignment(tiers[j], schemes[j], total, True)
+        with tracing.span("assign.finish"):
+            if not has_shared:
+                done.update(_batch_candidate_finish(
+                    solve_idx, cells, masked_b, stored_b, maskeds, storeds, As,
+                    cap_alls, finite_alls, Ns, K, max_candidates))
             else:
-                tiers, schemes = fallback if fallback is not None else (
-                    [np.zeros(Ns[t], np.int64) for t in solve_idx],
-                    [np.zeros(Ns[t], np.int64) for t in solve_idx])
-                for j, t in enumerate(solve_idx):
-                    done[t] = Assignment(tiers[j], schemes[j],
-                                         float("inf"), False)
+                joint = _dedupe_candidates(
+                    (cells[r].ravel() for r in range(cells.shape[0])),
+                    max_candidates)
+                best_score = float("inf")
+                best_state = None
+                fallback = None
+                for cand in joint:
+                    grid = cand.reshape(Tp, n_max)
+                    tiers = [grid[j, :Ns[t]] // K
+                             for j, t in enumerate(solve_idx)]
+                    schemes = [grid[j, :Ns[t]] % K
+                               for j, t in enumerate(solve_idx)]
+                    if fallback is None:
+                        fallback = ([x.copy() for x in tiers],
+                                    [x.copy() for x in schemes])
+                    m_l = [maskeds[t] for t in solve_idx]
+                    s_l = [storeds[t] for t in solve_idx]
+                    A_l = [As[t] for t in solve_idx]
+                    c_l = [cap_alls[t] for t in solve_idx]
+                    f_l = [finite_alls[t] for t in solve_idx]
+                    uses = []
+                    dead = False
+                    for j in range(Tp):
+                        use = _repair_vec(tiers[j], schemes[j], m_l[j], s_l[j],
+                                          A_l[j], c_l[j], f_l[j])
+                        if use is None:
+                            dead = True
+                            break
+                        uses.append(use)
+                    if dead:
+                        continue
+                    su = _fleet_repair_shared(tiers, schemes, uses, m_l, s_l,
+                                              A_l, c_l, f_l, A_sh, scap,
+                                              finite_sh)
+                    if su is None:
+                        continue
+                    _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l,
+                                  f_l, A_sh, scap, finite_sh, su)
+                    score = sum(
+                        float(m_l[j][np.arange(Ns[t]), tiers[j],
+                                     schemes[j]].sum())
+                        for j, t in enumerate(solve_idx))
+                    if score < BIG and score < best_score:
+                        best_score = score
+                        best_state = ([x.copy() for x in tiers],
+                                      [x.copy() for x in schemes])
+                if best_state is not None:
+                    tiers, schemes = best_state
+                    for j, t in enumerate(solve_idx):
+                        total = float(maskeds[t][np.arange(Ns[t]), tiers[j],
+                                                 schemes[j]].sum())
+                        done[t] = Assignment(tiers[j], schemes[j], total, True)
+                else:
+                    tiers, schemes = fallback if fallback is not None else (
+                        [np.zeros(Ns[t], np.int64) for t in solve_idx],
+                        [np.zeros(Ns[t], np.int64) for t in solve_idx])
+                    for j, t in enumerate(solve_idx):
+                        done[t] = Assignment(tiers[j], schemes[j],
+                                             float("inf"), False)
 
     assignments = [done[t] for t in range(T)]
     feasible = all(a.feasible for a in assignments)

@@ -152,7 +152,7 @@ class _KeptFeatures(CompressionPredictor):
     def features(self, tables, layout, **kw):
         self.tables, self.sizes = tables, kw["sizes"]
         with _Span("encode_dtype_classes"):
-            self.encoded = encode_dtype_classes(tables)
+            self.encoded = encode_dtype_classes(tables, kw.get("sources"))
         with _Span(f"features {kw['feature_backend']}"):
             self.X = super().features(tables, layout, encoded=self.encoded,
                                       **kw)

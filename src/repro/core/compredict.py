@@ -21,7 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import ml, tracing
-from repro.data.tables import (ClassCodes, Table, dtype_class,
+from repro.data.tables import (ClassCodes, Source, Table, dtype_class,
                                encode_dtype_classes, DTYPE_CLASSES)
 from repro.storage.codecs import Codec, default_codecs, measure
 
@@ -153,12 +153,14 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
                            sizes: Optional[Sequence[int]] = None,
                            n_buckets: int = 5,
                            encoded: Optional[Dict[str, ClassCodes]] = None,
+                           sources: Optional[Sequence[Source]] = None,
                            ) -> np.ndarray:
     """(N, F) feature matrix for N partitions in one pass.
 
     backend 'numpy' loops :func:`extract_features`; 'jnp' and 'pallas'
     dictionary-encode all partitions once (or reuse ``encoded`` from
-    :func:`repro.data.tables.encode_dtype_classes`) and compute every
+    :func:`repro.data.tables.encode_dtype_classes`, to which ``sources``,
+    each table's source table and rows, is passed) and compute every
     entropy feature in a single batched device dispatch — the COMPREDICT
     hot path for ``CompressStage``/``StreamingEngine`` re-prediction.
     'pallas' always compiles the kernel for the device; 'interpret' runs
@@ -184,7 +186,7 @@ def extract_features_batch(tables: Sequence[Table], layout: str,
     enc = encoded
     if enc is None:
         with tracing.span("features.encode"):
-            enc = encode_dtype_classes(tables)
+            enc = encode_dtype_classes(tables, sources)
     with tracing.span("features.entropy"):
         per_class = {d: _batched_entropy_columns(
             enc[d], n_buckets if kind == "bucketed" else 1, backend)
@@ -338,6 +340,7 @@ class CompressionPredictor:
                        layout: str, *,
                        sizes: Optional[Sequence[int]] = None,
                        feature_backend: Optional[str] = None,
+                       sources: Optional[Sequence[Source]] = None,
                        ) -> Tuple[np.ndarray, np.ndarray]:
         """(N,K) ratio and decompression-sec/GB matrices for OPTASSIGN.
 
@@ -345,14 +348,15 @@ class CompressionPredictor:
         :func:`extract_features_batch` (backend from ``feature_backend`` or
         the constructor default) and each per-(scheme, target) model
         predicts the whole batch in one call — no N×K Python loop.
-        ``sizes`` forwards known serialized byte counts."""
+        ``sizes`` forwards known serialized byte counts, ``sources`` each
+        table's source table and rows."""
         N, K = len(tables), len(schemes)
         R = np.ones((N, K))
         D = np.zeros((N, K))
         if N == 0:
             return R, D
         X = self.features(tables, layout, sizes=sizes,
-                          feature_backend=feature_backend)
+                          feature_backend=feature_backend, sources=sources)
         for k, s in enumerate(schemes):
             if s == "none":
                 continue                       # (1, 0) by definition
@@ -366,12 +370,14 @@ class CompressionPredictor:
                  sizes: Optional[Sequence[int]] = None,
                  feature_backend: Optional[str] = None,
                  encoded: Optional[Dict[str, ClassCodes]] = None,
+                 sources: Optional[Sequence[Source]] = None,
                  ) -> np.ndarray:
         """The (N, F) feature matrix :meth:`predict_matrix` feeds its
         models: one :func:`extract_features_batch` pass with
         ``feature_backend`` (else the constructor default); ``encoded``
-        reuses class codes from :func:`encode_dtype_classes`."""
+        reuses class codes from :func:`encode_dtype_classes`, ``sources``
+        (each table's source table and rows) goes to it."""
         return extract_features_batch(
             tables, layout, self.feature_kind,
             feature_backend or self.feature_backend, sizes=sizes,
-            encoded=encoded)
+            encoded=encoded, sources=sources)

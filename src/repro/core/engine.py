@@ -38,7 +38,7 @@ from repro.core.costs import (CostTable, Weights, cost_tensor,
                               move_egress_cents_gb, sla_penalty_tensor)
 from repro.core.optassign import (Assignment, capacitated_assign,
                                   greedy_assign, lock_schemes)
-from repro.data.tables import Table
+from repro.data.tables import Source, Table
 from repro.storage.codecs import available_schemes, codec_by_name, measure
 
 
@@ -111,6 +111,8 @@ class PartitionedData:
     raw_bytes: List[bytes]
     spans_gb: np.ndarray              # (N,)
     rho: np.ndarray                   # (N,)
+    # (source table, row indices) of each table, for the feature encoding
+    sources: Optional[List[Source]] = None
 
 
 @dataclasses.dataclass
@@ -395,9 +397,14 @@ class PartitionStage:
     @staticmethod
     def _partition_tables(parts: Sequence[datapart.Partition],
                           file_rows: Dict[str, Tuple[Table, np.ndarray]],
-                          ) -> List[Table]:
-        """Materialize each partition as the concatenation of its files' rows."""
-        out: List[Table] = []
+                          ) -> Tuple[List[Table], List[Source]]:
+        """Materialize each partition as the concatenation of its files' rows.
+
+        Returns the tables and, for each, its ``(source table, sorted row
+        indices)``, which lets the feature encoding render a row shared by
+        several partitions once."""
+        tables: List[Table] = []
+        sources: List[Source] = []
         for p in parts:
             per_table: Dict[str, List[np.ndarray]] = {}
             for f in sorted(p.files):
@@ -408,8 +415,9 @@ class PartitionStage:
             t0 = [file_rows[f][0] for f in sorted(p.files)
                   if file_rows[f][0].name == name][0]
             idx = np.sort(np.concatenate(per_table[name]))
-            out.append(t0.select(idx))
-        return out
+            tables.append(t0.select(idx))
+            sources.append((t0, idx))
+        return tables, sources
 
     def __call__(self, parts: List[datapart.Partition],
                  file_rows: Dict[str, Tuple[Table, np.ndarray]],
@@ -438,11 +446,12 @@ class PartitionStage:
                 for group in by_table.values():
                     merged.extend(datapart.merge_all(group))
             with tracing.span("partition.materialize"):
-                tables = self._partition_tables(merged, file_rows)
+                tables, sources = self._partition_tables(merged, file_rows)
                 raw_bytes = [t.serialize(cfg.layout) for t in tables]
             spans_gb = np.array([len(b) / 1e9 for b in raw_bytes])
             rho = np.array([p.rho for p in merged])
-            return PartitionedData(merged, tables, raw_bytes, spans_gb, rho)
+            return PartitionedData(merged, tables, raw_bytes, spans_gb, rho,
+                                   sources)
 
 
 class CompressStage:
@@ -482,7 +491,8 @@ class CompressStage:
                     Rm, Dm = pred.predict_matrix(
                         data.tables, schemes, cfg.layout,
                         sizes=[len(b) for b in data.raw_bytes],
-                        feature_backend=cfg.feature_backend)
+                        feature_backend=cfg.feature_backend,
+                        sources=data.sources)
                     R = Rm
                     # sec/GB -> sec per partition
                     D = Dm * data.spans_gb[:, None]
@@ -1044,23 +1054,24 @@ def compredict_rd_fn(predictor, file_rows: Dict[str, Tuple[Table, np.ndarray]],
     no re-materialization or re-serialization on later batches; the cache
     is pruned to the live partition set each call. Returned D is
     whole-partition seconds, as :class:`PlacementProblem` expects."""
-    cache: Dict[FrozenSet[str], Tuple[Table, int]] = {}
+    cache: Dict[FrozenSet[str], Tuple[Table, int, Source]] = {}
 
     def rd_fn(parts: List[datapart.Partition],
               schemes: Sequence[str]) -> Tuple[np.ndarray, np.ndarray]:
         missing = [p for p in parts if p.files not in cache]
         if missing:
-            for p, t in zip(missing,
-                            PartitionStage._partition_tables(missing,
-                                                             file_rows)):
-                cache[p.files] = (t, t.nbytes(layout))
+            for p, t, src in zip(missing, *PartitionStage._partition_tables(
+                    missing, file_rows)):
+                cache[p.files] = (t, t.nbytes(layout), src)
         for stale in set(cache) - {p.files for p in parts}:
             del cache[stale]
         tables = [cache[p.files][0] for p in parts]
         sizes = [cache[p.files][1] for p in parts]
+        sources = [cache[p.files][2] for p in parts]
         spans_gb = np.array([p.span for p in parts], np.float64)
         R, Dm = predictor.predict_matrix(tables, schemes, layout, sizes=sizes,
-                                         feature_backend=feature_backend)
+                                         feature_backend=feature_backend,
+                                         sources=sources)
         return R, Dm * spans_gb[:, None]
     return rd_fn
 

@@ -26,6 +26,9 @@ import jax
 PREFIX = "scope:"
 
 
-def span(name: str) -> jax.profiler.TraceAnnotation:
-    """A host span ``scope:<name>``, used as ``with span("gpart"): ...``."""
-    return jax.profiler.TraceAnnotation(PREFIX + name)
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span ``scope:<name>``, used as ``with span("gpart"): ...``.
+
+    Keyword arguments (numbers or strings) go with the span as metadata:
+    the trace's event keeps the name and carries each as a stat."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **args)

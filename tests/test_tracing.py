@@ -19,7 +19,9 @@ from repro.storage.codecs import available_schemes, codec_by_name
 # (span, the span it opens inside), in the order a plan opens them
 LAKE = [("plan", None), ("partition", "plan"), ("gpart", "partition"),
         ("partition.materialize", "partition"), ("compress", "plan"),
-        ("features.encode", "compress"), ("features.entropy", "compress"),
+        ("features.encode", "compress"),
+        ("features.encode.render", "features.encode"),
+        ("features.entropy", "compress"),
         ("assign", "plan"), ("billing", "plan")]
 FLEET_UNCAPPED = [("fleet.plan", None), ("assign", "fleet.plan"),
                   ("assign.inputs", "assign"), ("billing", "fleet.plan")]
@@ -35,7 +37,7 @@ def opened(monkeypatch):
     seen, stack = [], []
 
     @contextlib.contextmanager
-    def record(name):
+    def record(name, **args):
         seen.append((name, stack[-1] if stack else None))
         stack.append(name)
         try:
@@ -148,3 +150,8 @@ def test_profiler_trace_holds_the_spans_on_the_host_plane(lake, tmp_path):
     spans, planes = _host_spans(path)
     assert planes and all(p.startswith("/host:") for p in planes)
     assert _enclosing(spans) == LAKE + FLEET_CAPPED
+    args, = [dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events
+             if e.name == tracing.PREFIX + "features.encode.render"]
+    assert 0 < args["rendered"] <= args["values"]

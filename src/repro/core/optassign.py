@@ -1020,6 +1020,83 @@ def _fleet_polish(tiers, schemes, uses, maskeds, storeds, As, cap_alls,
             return
 
 
+def _fleet_finish_shared(solve_idx, cells: np.ndarray, maskeds, storeds, As,
+                         cap_alls, finite_alls, Ns, K: int, A_sh: np.ndarray,
+                         cap_sh: np.ndarray, finite_sh: np.ndarray,
+                         max_candidates: int) -> dict:
+    """Repair + polish finish for a fleet coupled by shared rows.
+
+    A candidate is one scan step's cells for every tenant at once. Three
+    passes run over the joint candidates, each in its own span: every
+    tenant's :func:`_repair_vec`, then :func:`_fleet_repair_shared` on the
+    candidates that survived, then :func:`_fleet_polish` and the f64
+    score. A candidate that a repair cannot make feasible drops out. Each
+    candidate's work touches only its own arrays, so the passes give what
+    a candidate-by-candidate loop gives: the first candidate with the
+    least score wins. Returns ``{tenant: Assignment}`` over ``solve_idx``.
+    """
+    Tp = len(solve_idx)
+    n_max = cells.shape[2]
+    m_l = [maskeds[t] for t in solve_idx]
+    s_l = [storeds[t] for t in solve_idx]
+    A_l = [As[t] for t in solve_idx]
+    c_l = [cap_alls[t] for t in solve_idx]
+    f_l = [finite_alls[t] for t in solve_idx]
+    size = dict(tenants=Tp, datasets=sum(Ns[t] for t in solve_idx))
+    states = []
+    for cand in _dedupe_candidates(
+            (cells[r].ravel() for r in range(cells.shape[0])),
+            max_candidates):
+        grid = cand.reshape(Tp, n_max)
+        states.append(([grid[j, :Ns[t]] // K for j, t in enumerate(solve_idx)],
+                       [grid[j, :Ns[t]] % K for j, t in enumerate(solve_idx)]))
+    fallback = (([x.copy() for x in states[0][0]],
+                 [x.copy() for x in states[0][1]]) if states else
+                ([np.zeros(Ns[t], np.int64) for t in solve_idx],
+                 [np.zeros(Ns[t], np.int64) for t in solve_idx]))
+
+    with tracing.span("assign.repair", candidates=len(states), **size):
+        repaired = []
+        for tiers, schemes in states:
+            uses = []
+            for j in range(Tp):
+                use = _repair_vec(tiers[j], schemes[j], m_l[j], s_l[j],
+                                  A_l[j], c_l[j], f_l[j])
+                if use is None:
+                    break
+                uses.append(use)
+            else:
+                repaired.append((tiers, schemes, uses))
+
+    with tracing.span("assign.shared_repair", candidates=len(repaired),
+                      **size):
+        shared = []
+        for tiers, schemes, uses in repaired:
+            su = _fleet_repair_shared(tiers, schemes, uses, m_l, s_l, A_l,
+                                      c_l, f_l, A_sh, cap_sh, finite_sh)
+            if su is not None:
+                shared.append((tiers, schemes, uses, su))
+
+    with tracing.span("assign.polish", candidates=len(shared), **size):
+        best_score, best = float("inf"), None
+        for tiers, schemes, uses, su in shared:
+            _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l, f_l,
+                          A_sh, cap_sh, finite_sh, su)
+            score = sum(float(m_l[j][np.arange(Ns[t]), tiers[j],
+                                     schemes[j]].sum())
+                        for j, t in enumerate(solve_idx))
+            if score < BIG and score < best_score:
+                best_score, best = score, (tiers, schemes)
+
+    if best is None:
+        return {t: Assignment(fallback[0][j], fallback[1][j], float("inf"),
+                              False) for j, t in enumerate(solve_idx)}
+    return {t: Assignment(best[0][j], best[1][j],
+                          float(maskeds[t][np.arange(Ns[t]), best[0][j],
+                                           best[1][j]].sum()), True)
+            for j, t in enumerate(solve_idx)}
+
+
 @dataclasses.dataclass
 class FleetAssignment:
     """Result of one batched fleet solve.
@@ -1214,65 +1291,10 @@ def capacitated_assign_batch(
                     solve_idx, cells, masked_b, stored_b, maskeds, storeds, As,
                     cap_alls, finite_alls, Ns, K, max_candidates))
             else:
-                joint = _dedupe_candidates(
-                    (cells[r].ravel() for r in range(cells.shape[0])),
-                    max_candidates)
-                best_score = float("inf")
-                best_state = None
-                fallback = None
-                for cand in joint:
-                    grid = cand.reshape(Tp, n_max)
-                    tiers = [grid[j, :Ns[t]] // K
-                             for j, t in enumerate(solve_idx)]
-                    schemes = [grid[j, :Ns[t]] % K
-                               for j, t in enumerate(solve_idx)]
-                    if fallback is None:
-                        fallback = ([x.copy() for x in tiers],
-                                    [x.copy() for x in schemes])
-                    m_l = [maskeds[t] for t in solve_idx]
-                    s_l = [storeds[t] for t in solve_idx]
-                    A_l = [As[t] for t in solve_idx]
-                    c_l = [cap_alls[t] for t in solve_idx]
-                    f_l = [finite_alls[t] for t in solve_idx]
-                    uses = []
-                    dead = False
-                    for j in range(Tp):
-                        use = _repair_vec(tiers[j], schemes[j], m_l[j], s_l[j],
-                                          A_l[j], c_l[j], f_l[j])
-                        if use is None:
-                            dead = True
-                            break
-                        uses.append(use)
-                    if dead:
-                        continue
-                    su = _fleet_repair_shared(tiers, schemes, uses, m_l, s_l,
-                                              A_l, c_l, f_l, A_sh, scap,
-                                              finite_sh)
-                    if su is None:
-                        continue
-                    _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l,
-                                  f_l, A_sh, scap, finite_sh, su)
-                    score = sum(
-                        float(m_l[j][np.arange(Ns[t]), tiers[j],
-                                     schemes[j]].sum())
-                        for j, t in enumerate(solve_idx))
-                    if score < BIG and score < best_score:
-                        best_score = score
-                        best_state = ([x.copy() for x in tiers],
-                                      [x.copy() for x in schemes])
-                if best_state is not None:
-                    tiers, schemes = best_state
-                    for j, t in enumerate(solve_idx):
-                        total = float(maskeds[t][np.arange(Ns[t]), tiers[j],
-                                                 schemes[j]].sum())
-                        done[t] = Assignment(tiers[j], schemes[j], total, True)
-                else:
-                    tiers, schemes = fallback if fallback is not None else (
-                        [np.zeros(Ns[t], np.int64) for t in solve_idx],
-                        [np.zeros(Ns[t], np.int64) for t in solve_idx])
-                    for j, t in enumerate(solve_idx):
-                        done[t] = Assignment(tiers[j], schemes[j],
-                                             float("inf"), False)
+                done.update(_fleet_finish_shared(
+                    solve_idx, cells, maskeds, storeds, As, cap_alls,
+                    finite_alls, Ns, K, A_sh, scap, finite_sh,
+                    max_candidates))
 
     assignments = [done[t] for t in range(T)]
     feasible = all(a.feasible for a in assignments)

@@ -28,17 +28,32 @@ FLEET_UNCAPPED = [("fleet.plan", None), ("assign", "fleet.plan"),
 FLEET_CAPPED = FLEET_UNCAPPED[:3] + [("assign.scan", "assign"),
                                      ("assign.finish", "assign"),
                                      ("billing", "fleet.plan")]
+# one quota shared by the fleet: the finish runs its three passes
+FLEET_SHARED = FLEET_CAPPED[:5] + [("assign.repair", "assign.finish"),
+                                   ("assign.shared_repair", "assign.finish"),
+                                   ("assign.polish", "assign.finish"),
+                                   ("billing", "fleet.plan")]
 SCHEMES = ("none", "lz4", "zstd3")
+
+
+class _Opened(list):
+    """(name, enclosing span) of every span opened; ``args[name]`` holds
+    the keyword arguments of the last opening of ``name``."""
+
+    def __init__(self):
+        super().__init__()
+        self.args = {}
 
 
 @pytest.fixture
 def opened(monkeypatch):
     """Every span opened while the test runs, as (name, enclosing span)."""
-    seen, stack = [], []
+    seen, stack = _Opened(), []
 
     @contextlib.contextmanager
     def record(name, **args):
         seen.append((name, stack[-1] if stack else None))
+        seen.args[name] = args
         stack.append(name)
         try:
             yield
@@ -84,6 +99,18 @@ def _fleet(T: int, capped: bool, seed: int = 0):
     return FleetEngine(table, cfg), probs
 
 
+def _shared_fleet(T: int, seed: int = 0):
+    """The uncapped fleet under one cool-tier quota that all T tenants
+    share, at half their unconstrained use of the tier: it binds."""
+    fe, probs = _fleet(T, capped=False, seed=seed)
+    quota = np.full(4, np.inf)
+    free = FleetEngine(fe.table, fe.cfg, shared_tier_groups=np.arange(4),
+                       shared_capacity_gb=quota.copy())
+    quota[2] = 0.5 * free.assign_batch(probs).shared_use_gb[2]
+    return FleetEngine(fe.table, fe.cfg, shared_tier_groups=np.arange(4),
+                       shared_capacity_gb=quota), probs
+
+
 def test_lake_plan_opens_each_span_once_nested(opened, lake):
     eng, parts, file_rows = lake
     eng.run(parts, file_rows)
@@ -108,6 +135,24 @@ def test_fleet_spans_do_not_grow_with_tenants(opened, capped):
         opened.clear()
         fe.solve(probs)
         assert opened == (FLEET_CAPPED if capped else FLEET_UNCAPPED), T
+
+
+@pytest.mark.parametrize("T", [4, 64])
+def test_shared_quota_plan_opens_the_finish_passes_once(opened, T):
+    fe, probs = _shared_fleet(T, seed=T)
+    opened.clear()
+    plan = fe.solve(probs)
+    assert opened == FLEET_SHARED
+    assert plan.fleet.feasible
+    datasets = sum(p.n for p in probs)
+    for name in ("assign.repair", "assign.shared_repair", "assign.polish"):
+        args = opened.args[name]
+        assert args["tenants"] == T and args["datasets"] == datasets
+        assert 1 <= args["candidates"] <= 16
+    # each pass sees the candidates the one before it let through
+    assert (opened.args["assign.repair"]["candidates"]
+            >= opened.args["assign.shared_repair"]["candidates"]
+            >= opened.args["assign.polish"]["candidates"])
 
 
 def _host_spans(path):

@@ -34,7 +34,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -311,46 +311,117 @@ def _repair_vec(tier: np.ndarray, scheme: np.ndarray, masked: np.ndarray,
     return None
 
 
+@lru_cache(maxsize=64)
+def _gates(a: bytes, fin: bytes, C: int, L: int, K: int):
+    """What the 1-swap descent reads of its constraint rows ``A`` (C, L)
+    and their finite mask, given as bytes: the tiers a finite row caps,
+    their flat cells (ascending), ``A`` as floats, and the masks of the
+    room tables. The same rows come back call after call (every candidate
+    of a solve, every sweep over a tenant), so each is built once.
+
+    ``room[n, l] = min(T1[tier[n], l], T2[tier[n], l] + stored_cur[n])``,
+    T1 the least slack over the finite rows that hold l and not tier[n],
+    T2 over those that hold both: the vacated bytes free room only in the
+    rows that hold the row's own tier. Rounding is monotone, so adding
+    them after the min gives the min of the per-row sums bit for bit.
+    ``m_t[c, (i, l1, l)]`` selects the rows of T1 (i = 0) and T2 (i = 1).
+    """
+    A = np.frombuffer(a, bool).reshape(C, L)
+    Af = A & np.frombuffer(fin, bool)[:, None]
+    capped = Af.any(0)
+    gated = np.flatnonzero(capped)
+    gcells = np.flatnonzero(np.repeat(capped, K))
+    m_t = (Af[:, None, None, gated]
+           & (np.arange(2)[:, None] == A[:, None, :])[..., None])
+    out = gated, gcells, A.astype(np.float64), m_t.reshape(C, -1)
+    for x in out:                   # shared by every caller: read-only
+        x.setflags(write=False)
+    return out
+
+
 def _local_search_vec(tier: np.ndarray, scheme: np.ndarray, use: np.ndarray,
                       masked: np.ndarray, stored: np.ndarray, A: np.ndarray,
                       cap_all: np.ndarray, finite_all: np.ndarray,
-                      max_moves: Optional[int] = None) -> None:
-    """Best-improvement 1-swap descent with a full (N,L,K) delta matrix.
+                      max_moves: Optional[int] = None) -> int:
+    """Best-improvement 1-swap descent over the (N,L,K) delta matrix.
+
+    Each step takes the cell of least ``masked - cur`` (first occurrence in
+    the flattened grid) that fits the room its tier leaves in every finite
+    constraint, and stops when no move gains more than 1e-12. A move
+    changes one row of the delta matrix and the usage of two tiers, so the
+    descent rescans only what a move can change: the matrix is kept across
+    moves and only the moved row is rewritten; on tiers that no finite
+    constraint covers each row's best cell is cached and recomputed only
+    for the moved row; the cells of the capacity-gated tiers are rescanned
+    every step, against a room built from an (L, L) table instead of an
+    (N, C, L) reduction. The moves are those of a full rescan, in the same
+    order. Returns the number of moves made.
 
     ``max_moves`` overrides the default ``8 * N + 64`` budget so the
     lockstep fleet descent can hand its tail rows over mid-trajectory
     with their remaining budget intact.
     """
     N, L, K = masked.shape
+    budget = 8 * N + 64 if max_moves is None else max_moves
+    if N == 0 or budget <= 0:
+        return 0
     n_idx = np.arange(N)
-    Af = A & finite_all[:, None]                    # (C, L)
-    A_f = A.astype(np.float64)
-    any_finite = bool(finite_all.any())
-    for _ in range(8 * N + 64 if max_moves is None else max_moves):
-        cur = masked[n_idx, tier, scheme]
-        stored_cur = stored[n_idx, tier, scheme]
-        if any_finite:
+    gated, gcells, A_f, m_t = _gates(
+        A.astype(bool, copy=False).tobytes(),
+        finite_all.astype(bool, copy=False).tobytes(), *A.shape, K)
+    G = gated.size
+    m2 = masked.reshape(N, L * K)
+    cur = masked[n_idx, tier, scheme]
+    stored_cur = stored[n_idx, tier, scheme]
+    # bf[n, j]: the gain of moving n to flat cell j, inf where infeasible
+    # or gated; the gated tiers' cells sit apart in base_g (N, G, K)
+    feas = m2 < BIG
+    bf = np.where(feas, m2 - cur[:, None], np.inf)
+    # a move lands on a feasible cell, so the moved row's cur is finite
+    # and m_inf - cur rewrites the row as the where above would
+    m_inf = np.where(feas, m2, np.inf)
+    if G:
+        base_g = bf[:, gcells].reshape(N, G, K)
+        bf[:, gcells] = np.inf
+        stored_g = stored[:, gated]
+    bf_idx = bf.argmin(1)
+    bf_val = bf[n_idx, bf_idx]
+    moves = 0
+    for _ in range(budget):
+        n = int(bf_val.argmin())                    # first row, free cells
+        v, j = bf_val[n], int(bf_idx[n])
+        if G:
             # einsum, not @: the lockstep fleet descent replicates this
             # exact ascending-l accumulation for bitwise-equal trajectories
-            use_c = np.einsum("cl,l->c", A_f, use)
-            # slack[n, c]: room left in constraint c once n vacates its cell
-            slack = ((cap_all - use_c)[None, :]
-                     + A[:, tier].T * stored_cur[:, None])           # (N, C)
-            # per-destination room = tightest finite constraint containing it
-            room = np.where(Af[None, :, :], slack[:, :, None],
-                            np.inf).min(1)                           # (N, L)
-            ok = (masked < BIG) & (stored <= room[:, :, None] + 1e-9)
-        else:
-            ok = masked < BIG
-        delta = np.where(ok, masked - cur[:, None, None], np.inf)
-        j = int(delta.argmin())
-        n, rem = divmod(j, L * K)
-        l2, k2 = divmod(rem, K)
-        if not delta[n, l2, k2] < -1e-12:
+            slack0 = cap_all - np.einsum("cl,l->c", A_f, use)
+            t = np.where(m_t, slack0[:, None], np.inf).min(0).reshape(
+                2, L, G).take(tier, 1)                         # (2, N, G)
+            room = np.minimum(t[0], t[1] + stored_cur[:, None])
+            dg = np.where(stored_g <= room[:, :, None] + 1e-9, base_g,
+                          np.inf).reshape(N, G * K)
+            ng, cg = divmod(int(dg.argmin()), G * K)  # first row, gated
+            vg = dg[ng, cg]
+            # the first row holding the least value moves; within it the
+            # lower flat index wins a tie between a gated and a free cell
+            if vg < v or (vg == v and (ng < n or (
+                    ng == n and gcells[cg] < j))):
+                n, v, j = ng, vg, int(gcells[cg])
+        if not v < -1e-12:
             break
+        l2, k2 = divmod(j, K)
         use[tier[n]] -= stored[n, tier[n], scheme[n]]
         use[l2] += stored[n, l2, k2]
         tier[n], scheme[n] = l2, k2
+        moves += 1
+        cur[n] = masked[n, l2, k2]
+        stored_cur[n] = stored[n, l2, k2]
+        row = m_inf[n] - cur[n]
+        if G:
+            base_g[n] = row[gcells].reshape(G, K)
+            row[gcells] = np.inf
+        j = int(row.argmin())
+        bf_idx[n], bf_val[n] = j, row[j]
+    return moves
 
 
 def _step0(masked: np.ndarray, cap_all: np.ndarray,
@@ -435,11 +506,12 @@ def _lockstep_local_search(tier_r: np.ndarray, scheme_r: np.ndarray,
                            budget: np.ndarray) -> None:
     """Vectorized best-improvement 1-swap descent over independent rows.
 
-    Replicates :func:`_local_search_vec` move-for-move for every (tenant,
-    candidate) row at once: the same einsum ``use_c`` accumulation, the
-    same slack/room/ok/delta expressions, the same first-occurrence argmin
-    over the flattened cell grid (padding cells are ``+inf`` and can never
-    win), and the same per-row iteration budget ``8 * N + 64``. Rows
+    Makes the moves of :func:`_local_search_vec` for every (tenant,
+    candidate) row at once, rescanning every row's whole delta matrix each
+    round: the same einsum ``use_c`` accumulation, the same room and
+    gains, the same first-occurrence argmin over the flattened cell grid
+    (padding cells are ``+inf`` and can never win), and the same per-row
+    iteration budget ``8 * N + 64``. Rows
     deactivate independently, so the Python-level loop runs once per step
     of the longest trajectory instead of once per row.
     """
@@ -995,12 +1067,14 @@ def _fleet_repair_shared(tiers, schemes, uses, maskeds, storeds, As,
 
 def _fleet_polish(tiers, schemes, uses, maskeds, storeds, As, cap_alls,
                   finite_alls, A_sh, cap_sh, finite_sh,
-                  su: np.ndarray) -> None:
+                  su: np.ndarray) -> int:
     """Round-robin 1-swap descent under the shared rows: each tenant runs
     :func:`_local_search_vec` against its own constraints augmented with the
     shared rows at their *residual* caps (fleet cap minus the other tenants'
-    usage), sweeping until a full pass changes nothing."""
+    usage), sweeping until a full pass changes nothing. Returns the number
+    of moves made."""
     T = len(tiers)
+    moves = 0
     for _ in range(8):
         changed = False
         for t in range(T):
@@ -1011,13 +1085,15 @@ def _fleet_polish(tiers, schemes, uses, maskeds, storeds, As, cap_alls,
             cap_aug = np.concatenate([cap_alls[t], cap_sh - (su - own_sh)])
             fin_aug = np.concatenate([finite_alls[t], finite_sh])
             t0, k0 = tiers[t].copy(), schemes[t].copy()
-            _local_search_vec(tiers[t], schemes[t], uses[t], maskeds[t],
-                              storeds[t], A_aug, cap_aug, fin_aug)
+            moves += _local_search_vec(tiers[t], schemes[t], uses[t],
+                                       maskeds[t], storeds[t], A_aug,
+                                       cap_aug, fin_aug)
             if not ((tiers[t] == t0).all() and (schemes[t] == k0).all()):
                 changed = True
                 su += A_sh @ uses[t] - own_sh
         if not changed:
-            return
+            break
+    return moves
 
 
 def _fleet_finish_shared(solve_idx, cells: np.ndarray, maskeds, storeds, As,
@@ -1077,16 +1153,18 @@ def _fleet_finish_shared(solve_idx, cells: np.ndarray, maskeds, storeds, As,
             if su is not None:
                 shared.append((tiers, schemes, uses, su))
 
-    with tracing.span("assign.polish", candidates=len(shared), **size):
-        best_score, best = float("inf"), None
+    with tracing.span("assign.polish", candidates=len(shared),
+                      **size) as sp:
+        best_score, best, moves = float("inf"), None, 0
         for tiers, schemes, uses, su in shared:
-            _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l, f_l,
-                          A_sh, cap_sh, finite_sh, su)
+            moves += _fleet_polish(tiers, schemes, uses, m_l, s_l, A_l, c_l,
+                                   f_l, A_sh, cap_sh, finite_sh, su)
             score = sum(float(m_l[j][np.arange(Ns[t]), tiers[j],
                                      schemes[j]].sum())
                         for j, t in enumerate(solve_idx))
             if score < BIG and score < best_score:
                 best_score, best = score, (tiers, schemes)
+        sp.set_metadata(moves=moves)
 
     if best is None:
         return {t: Assignment(fallback[0][j], fallback[1][j], float("inf"),

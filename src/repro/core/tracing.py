@@ -30,5 +30,7 @@ def span(name: str, **args) -> jax.profiler.TraceAnnotation:
     """A host span ``scope:<name>``, used as ``with span("gpart"): ...``.
 
     Keyword arguments (numbers or strings) go with the span as metadata:
-    the trace's event keeps the name and carries each as a stat."""
+    the trace's event keeps the name and carries each as a stat. A count
+    known only at the end is set before the span closes, through
+    ``with span(name) as sp: ...; sp.set_metadata(key=value)``."""
     return jax.profiler.TraceAnnotation(PREFIX + name, **args)

@@ -38,11 +38,22 @@ SCHEMES = ("none", "lz4", "zstd3")
 
 class _Opened(list):
     """(name, enclosing span) of every span opened; ``args[name]`` holds
-    the keyword arguments of the last opening of ``name``."""
+    the keyword arguments of the last opening of ``name`` and the metadata
+    set on it before it closed."""
 
     def __init__(self):
         super().__init__()
         self.args = {}
+
+
+class _Span:
+    """What a recorded span yields: ``set_metadata`` adds to its args."""
+
+    def __init__(self, args: dict):
+        self.args = args
+
+    def set_metadata(self, **kw):
+        self.args.update(kw)
 
 
 @pytest.fixture
@@ -53,10 +64,10 @@ def opened(monkeypatch):
     @contextlib.contextmanager
     def record(name, **args):
         seen.append((name, stack[-1] if stack else None))
-        seen.args[name] = args
+        seen.args[name] = dict(args)
         stack.append(name)
         try:
-            yield
+            yield _Span(seen.args[name])
         finally:
             stack.pop()
 
@@ -153,6 +164,10 @@ def test_shared_quota_plan_opens_the_finish_passes_once(opened, T):
     assert (opened.args["assign.repair"]["candidates"]
             >= opened.args["assign.shared_repair"]["candidates"]
             >= opened.args["assign.polish"]["candidates"])
+    # the polish counts its moves when it closes; the other passes do not
+    moves = opened.args["assign.polish"]["moves"]
+    assert isinstance(moves, int) and moves >= 0
+    assert "moves" not in opened.args["assign.repair"]
 
 
 def _host_spans(path):

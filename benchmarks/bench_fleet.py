@@ -4,8 +4,8 @@ A fleet of T tenants (ragged sizes drawn around N partitions each) is
 solved two ways: T independent ``capacitated_assign`` calls — Python
 dispatch + a jit re-trace per distinct N + per-candidate host finish —
 and one ``capacitated_assign_batch`` dispatch (pad to ``(T, N_max)``,
-one jitted Lagrangian scan and one lockstep host finish over the whole
-fleet). Tenant caps are binding (the greedy-hottest tier is clamped to
+one jitted Lagrangian scan over the whole fleet, then the same host
+finish per tenant). Tenant caps are binding (the greedy-hottest tier is clamped to
 90% of its greedy usage) so both paths run the full scan+repair+swap
 pipeline rather than the greedy shortcut.
 

@@ -8,16 +8,16 @@ Solvers
                         (Thm 2) via min-cost flow == min-weight bipartite
                         matching on tier copies.
 ``capacitated_assign``  general capacitated case (strongly NP-hard, Thm 1):
-                        vectorized JAX Lagrangian dual ascent (jitted scan over
-                        all N*L*K cells) + argsort-based greedy repair +
-                        delta-matrix 1-swap local search; validated against
-                        ``brute_force`` in tests.
-``capacitated_assign_batch``  the fleet path: T ragged tenant problems padded
-                        into one (T, N_max, L, K) batch and solved by a single
+                        ``capacitated_assign_batch`` on one tenant; validated
+                        against ``brute_force`` in tests.
+``capacitated_assign_batch``  the one capacitated solver: T ragged tenant
+                        problems padded into one (T, N_max, L, K) batch, one
                         batched (optionally ``shard_map``-sharded) Lagrangian
-                        scan dispatch. Bit-identical per tenant to
-                        ``capacitated_assign`` when no *shared* (fleet-wide)
-                        capacity rows couple the tenants.
+                        scan over all N*L*K cells, then a host finish —
+                        argsort-based greedy repair + delta-matrix 1-swap
+                        local search per tenant, or, when *shared*
+                        (fleet-wide) capacity rows couple the tenants, a
+                        cross-tenant repair and polish.
 ``greedy_assign_batch``  batched unbounded path, one dispatch for T tenants.
 ``capacitated_assign_ref``  the original pure-Python solver, kept as the
                         correctness reference for the vectorized path.
@@ -220,40 +220,6 @@ def _constraint_rows(capacity_gb: np.ndarray,
     return A, cap_all
 
 
-@partial(jax.jit, static_argnames=("iters",))
-def _lagrangian_scan(masked: jnp.ndarray, stored: jnp.ndarray,
-                     cap: jnp.ndarray, finite_cap: jnp.ndarray,
-                     group_of_tier: jnp.ndarray, gcap: jnp.ndarray,
-                     finite_gcap: jnp.ndarray,
-                     step0: jnp.ndarray, iters: int):
-    """Jitted dual ascent over all N*L*K cells; one candidate per step.
-
-    Dualizes both the per-tier capacities and the group (per-provider)
-    capacities: a tier's effective multiplier is its own lambda plus its
-    group's. With no groups the group lambdas stay exactly zero.
-    """
-    N, L, K = masked.shape
-    G = gcap.shape[0]
-    flat_cost = masked.reshape(N, -1)
-    flat_stored = stored.reshape(N, -1)
-
-    def body(lam, it):
-        eff = lam[:L] + lam[L:][group_of_tier]
-        adj = flat_cost + (eff[None, :, None] * stored).reshape(N, -1)
-        idx = jnp.argmin(adj, axis=1)
-        chosen = jnp.take_along_axis(flat_stored, idx[:, None], axis=1)[:, 0]
-        use = jnp.zeros(L, masked.dtype).at[idx // K].add(chosen)
-        use_g = jnp.zeros(G, masked.dtype).at[group_of_tier].add(use)
-        grad = jnp.concatenate([jnp.where(finite_cap, use - cap, 0.0),
-                                jnp.where(finite_gcap, use_g - gcap, 0.0)])
-        lam = jnp.maximum(0.0, lam + step0 / (1.0 + it) * grad)
-        return lam, idx
-
-    _, cells = jax.lax.scan(body, jnp.zeros(L + G, masked.dtype),
-                            jnp.arange(iters, dtype=masked.dtype))
-    return cells                                    # (iters, N) flat indices
-
-
 def _repair_vec(tier: np.ndarray, scheme: np.ndarray, masked: np.ndarray,
                 stored: np.ndarray, A: np.ndarray, cap_all: np.ndarray,
                 finite_all: np.ndarray) -> Optional[np.ndarray]:
@@ -265,8 +231,9 @@ def _repair_vec(tier: np.ndarray, scheme: np.ndarray, masked: np.ndarray,
     Af = A & finite_all[:, None]                    # (C, L)
     A_f = A.astype(np.float64)
     for _ in range(4 * N + 8):
-        # einsum, not @: the batched fleet precheck replicates this exact
-        # ascending-l accumulation, so round-0 decisions agree bitwise
+        # einsum, not @: this ascending-l accumulation sets the low bits
+        # of use_c, and so the evictions that the benchmark's reference
+        # checks and tests/test_fleet.py's golden plans pinned
         use_c = np.einsum("cl,l->c", A_f, use)
         over = np.where(finite_all & (use_c > cap_all + 1e-9))[0]
         if over.size == 0:
@@ -341,8 +308,7 @@ def _gates(a: bytes, fin: bytes, C: int, L: int, K: int):
 
 def _local_search_vec(tier: np.ndarray, scheme: np.ndarray, use: np.ndarray,
                       masked: np.ndarray, stored: np.ndarray, A: np.ndarray,
-                      cap_all: np.ndarray, finite_all: np.ndarray,
-                      max_moves: Optional[int] = None) -> int:
+                      cap_all: np.ndarray, finite_all: np.ndarray) -> int:
     """Best-improvement 1-swap descent over the (N,L,K) delta matrix.
 
     Each step takes the cell of least ``masked - cur`` (first occurrence in
@@ -355,15 +321,12 @@ def _local_search_vec(tier: np.ndarray, scheme: np.ndarray, use: np.ndarray,
     for the moved row; the cells of the capacity-gated tiers are rescanned
     every step, against a room built from an (L, L) table instead of an
     (N, C, L) reduction. The moves are those of a full rescan, in the same
-    order. Returns the number of moves made.
-
-    ``max_moves`` overrides the default ``8 * N + 64`` budget so the
-    lockstep fleet descent can hand its tail rows over mid-trajectory
-    with their remaining budget intact.
+    order, at most ``8 * N + 64`` of them. Returns the number of moves
+    made.
     """
     N, L, K = masked.shape
-    budget = 8 * N + 64 if max_moves is None else max_moves
-    if N == 0 or budget <= 0:
+    budget = 8 * N + 64
+    if N == 0:
         return 0
     n_idx = np.arange(N)
     gated, gcells, A_f, m_t = _gates(
@@ -391,8 +354,10 @@ def _local_search_vec(tier: np.ndarray, scheme: np.ndarray, use: np.ndarray,
         n = int(bf_val.argmin())                    # first row, free cells
         v, j = bf_val[n], int(bf_idx[n])
         if G:
-            # einsum, not @: the lockstep fleet descent replicates this
-            # exact ascending-l accumulation for bitwise-equal trajectories
+            # einsum, not @: this ascending-l accumulation sets the low
+            # bits of the slack, and so which moves fit: the moves that
+            # tests/test_local_search.py's frozen descent and the
+            # benchmark's reference checks pinned
             slack0 = cap_all - np.einsum("cl,l->c", A_f, use)
             t = np.where(m_t, slack0[:, None], np.inf).min(0).reshape(
                 2, L, G).take(tier, 1)                         # (2, N, G)
@@ -451,30 +416,16 @@ def _dedupe_candidates(rows, max_candidates: int) -> List[np.ndarray]:
     return uniq
 
 
-def _dedupe_candidates_arr(arr: np.ndarray,
-                           max_candidates: int) -> List[np.ndarray]:
-    """:func:`_dedupe_candidates` for a contiguous (iters, N) matrix: one
-    ``np.unique`` over row bytes instead of a Python set — same unique
-    rows, same first-occurrence emission order, same head/tail truncation.
-    """
-    arr = np.ascontiguousarray(arr)
-    if arr.shape[1] == 0:
-        return [np.zeros(0, np.int64)]
-    keys = arr.view(np.dtype((np.void, arr.dtype.itemsize * arr.shape[1])))
-    _, first = np.unique(keys.ravel(), return_index=True)
-    uniq = [arr[i].astype(np.int64) for i in np.sort(first)]
-    if len(uniq) > max_candidates:
-        head = max_candidates // 4
-        uniq = uniq[:head] + uniq[-(max_candidates - head):]
-    return uniq
-
-
 def _best_from_candidates(uniq: List[np.ndarray], masked: np.ndarray,
                           stored: np.ndarray, A: np.ndarray,
                           cap_all: np.ndarray,
                           finite_all: np.ndarray) -> Assignment:
     """Repair + polish every candidate cell vector, keep the best f64 score.
-    The shared tail of the single-tenant and (uncoupled) fleet solvers."""
+
+    The finish of every solve without shared rows: one tenant, or each
+    tenant of an uncoupled fleet in turn. The first candidate of least
+    score wins; when no candidate survives repair, the first decode comes
+    back as the (infeasible) fallback."""
     N, _, K = masked.shape
     best: Optional[Assignment] = None
     fallback: Optional[Tuple[np.ndarray, np.ndarray]] = None
@@ -498,194 +449,20 @@ def _best_from_candidates(uniq: List[np.ndarray], masked: np.ndarray,
     return best
 
 
-def _lockstep_local_search(tier_r: np.ndarray, scheme_r: np.ndarray,
-                           use_r: np.ndarray, alive: np.ndarray,
-                           jrow: np.ndarray, masked_b: np.ndarray,
-                           stored_b: np.ndarray, A_fb: np.ndarray,
-                           Af_b: np.ndarray, cap_b: np.ndarray,
-                           budget: np.ndarray) -> None:
-    """Vectorized best-improvement 1-swap descent over independent rows.
-
-    Makes the moves of :func:`_local_search_vec` for every (tenant,
-    candidate) row at once, rescanning every row's whole delta matrix each
-    round: the same einsum ``use_c`` accumulation, the same room and
-    gains, the same first-occurrence argmin over the flattened cell grid
-    (padding cells are ``+inf`` and can never win), and the same per-row
-    iteration budget ``8 * N + 64``. Rows
-    deactivate independently, so the Python-level loop runs once per step
-    of the longest trajectory instead of once per row.
-    """
-    M, n_max = tier_r.shape
-    L, K = masked_b.shape[2], masked_b.shape[3]
-    n_idx = np.arange(n_max)
-    while alive.size:
-        if alive.size <= _LOCKSTEP_TAIL:
-            # the few long-trajectory survivors finish sequentially: rows
-            # are independent and the sequential descent applies the same
-            # update rule, so continuing with the remaining per-row move
-            # budget lands on the same fixed point bit-for-bit — without
-            # paying a full vectorized round per move for a handful of rows
-            for r in alive:
-                j = jrow[r]
-                _local_search_vec(tier_r[r], scheme_r[r], use_r[r],
-                                  masked_b[j], stored_b[j],
-                                  A_fb[j] != 0.0, cap_b[j],
-                                  np.isfinite(cap_b[j]),
-                                  max_moves=int(budget[r]))
-            return
-        jr = jrow[alive]
-        mrows = masked_b[jr]                                  # (A, N, L, K)
-        srows = stored_b[jr]
-        tr, sc = tier_r[alive], scheme_r[alive]
-        a_idx = np.arange(alive.size)[:, None]
-        cur = mrows[a_idx, n_idx[None, :], tr, sc]            # (A, N)
-        stored_cur = srows[a_idx, n_idx[None, :], tr, sc]
-        use_c = np.einsum("acl,al->ac", A_fb[jr], use_r[alive])
-        At = np.take_along_axis(A_fb[jr], tr[:, None, :], axis=2)
-        slack = ((cap_b[jr] - use_c)[:, None, :]
-                 + At.transpose(0, 2, 1) * stored_cur[:, :, None])
-        room = np.where(Af_b[jr][:, None, :, :], slack[..., None],
-                        np.inf).min(2)                        # (A, N, L)
-        ok = (mrows < BIG) & (srows <= room[..., None] + 1e-9)
-        delta = np.where(ok, mrows - cur[..., None, None], np.inf)
-        flat = delta.reshape(alive.size, -1)
-        jarg = flat.argmin(1)
-        dmin = flat[np.arange(alive.size), jarg]
-        g = np.where(dmin < -1e-12)[0]
-        if g.size == 0:
-            break
-        rows = alive[g]
-        n, rem = np.divmod(jarg[g], L * K)
-        l2, k2 = np.divmod(rem, K)
-        l1 = tier_r[rows, n]
-        k1 = scheme_r[rows, n]
-        jg = jrow[rows]
-        use_r[rows, l1] -= stored_b[jg, n, l1, k1]
-        use_r[rows, l2] += stored_b[jg, n, l2, k2]
-        tier_r[rows, n] = l2
-        scheme_r[rows, n] = k2
-        budget[rows] -= 1
-        alive = rows[budget[rows] > 0]
-
-
-def _batch_candidate_finish(solve_idx, cells: np.ndarray,
-                            masked_b: np.ndarray, stored_b: np.ndarray,
-                            maskeds, storeds, As, cap_alls, finite_alls,
-                            Ns, K: int, max_candidates: int) -> dict:
-    """Vectorized repair + 1-swap finish for the uncoupled fleet batch.
-
-    Bit-identical per tenant to running :func:`_dedupe_candidates` +
-    :func:`_best_from_candidates` in a loop (pinned by
-    ``tests/test_fleet.py``), but batched on host: one scatter computes
-    every candidate's usage, one einsum makes every round-0 feasibility
-    decision, only rows that actually violate a capacity fall back to the
-    sequential :func:`_repair_vec`, and all surviving rows descend in one
-    lockstep :func:`_lockstep_local_search`. This removes the per-row
-    Python/numpy dispatch that otherwise dominates fleet solves.
-    """
-    iters_n, Tp, n_max = cells.shape
-    L = masked_b.shape[2]
-    rows_of: List[List[int]] = [[] for _ in range(Tp)]
-    uniq_all: List[np.ndarray] = []
-    row_j: List[int] = []
-    for j in range(Tp):
-        t = solve_idx[j]
-        uniq = _dedupe_candidates_arr(cells[:, j, :Ns[t]], max_candidates)
-        for cand in uniq:
-            rows_of[j].append(len(uniq_all))
-            uniq_all.append(cand)
-            row_j.append(j)
-    M = len(uniq_all)
-    jrow = np.asarray(row_j)
-
-    # constraint rows, padded to a common C with inert (cap=inf) rows
-    C_max = max(As[t].shape[0] for t in solve_idx)
-    A_b = np.zeros((Tp, C_max, L), bool)
-    cap_b2 = np.full((Tp, C_max), np.inf)
-    fin_b = np.zeros((Tp, C_max), bool)
-    for j, t in enumerate(solve_idx):
-        C = As[t].shape[0]
-        A_b[j, :C] = As[t]
-        cap_b2[j, :C] = cap_alls[t]
-        fin_b[j, :C] = finite_alls[t]
-    A_fb = A_b.astype(np.float64)
-    Af_b = A_b & fin_b[:, :, None]
-
-    # decode candidates; keep each tenant's first decode as the fallback
-    tier_r = np.zeros((M, n_max), np.int64)
-    scheme_r = np.zeros((M, n_max), np.int64)
-    fallbacks = {}
-    for m, cand in enumerate(uniq_all):
-        tier_r[m, :cand.shape[0]] = cand // K
-        scheme_r[m, :cand.shape[0]] = cand % K
-        j = row_j[m]
-        if j not in fallbacks:
-            fallbacks[j] = (tier_r[m, :cand.shape[0]].copy(),
-                            scheme_r[m, :cand.shape[0]].copy())
-
-    # per-row usage: one scatter, ascending-n within each row, so it is
-    # bit-identical to _chosen_usage (padding rows add exact 0.0)
-    sval = stored_b[jrow[:, None], np.arange(n_max)[None, :], tier_r,
-                    scheme_r]
-    use_r = np.zeros((M, L))
-    np.add.at(use_r, (np.repeat(np.arange(M), n_max), tier_r.ravel()),
-              sval.ravel())
-
-    # round-0 repair decision for every row at once; only violating rows
-    # pay the sequential eviction loop
-    use_c0 = np.einsum("acl,al->ac", A_fb[jrow], use_r)
-    viol = (fin_b[jrow] & (use_c0 > cap_b2[jrow] + 1e-9)).any(1)
-    dead = np.zeros(M, bool)
-    for m in np.where(viol)[0]:
-        j = row_j[m]
-        t = solve_idx[j]
-        use = _repair_vec(tier_r[m, :Ns[t]], scheme_r[m, :Ns[t]],
-                          maskeds[t], storeds[t], As[t], cap_alls[t],
-                          finite_alls[t])
-        if use is None:
-            dead[m] = True
-        else:
-            use_r[m] = use
-
-    budget = 8 * np.asarray([Ns[solve_idx[j]] for j in row_j]) + 64
-    _lockstep_local_search(tier_r, scheme_r, use_r, np.where(~dead)[0],
-                           jrow, masked_b, stored_b, A_fb, Af_b, cap_b2,
-                           budget)
-
-    out = {}
-    for j in range(Tp):
-        t = solve_idx[j]
-        n_t = Ns[t]
-        best: Optional[Assignment] = None
-        for m in rows_of[j]:
-            if dead[m]:
-                continue
-            tr, sc = tier_r[m, :n_t], scheme_r[m, :n_t]
-            total = float(maskeds[t][np.arange(n_t), tr, sc].sum())
-            if total < BIG and (best is None or total < best.cost):
-                best = Assignment(tr.copy(), sc.copy(), total, True)
-        if best is None:
-            ftr, fsc = fallbacks.get(
-                j, (np.zeros(n_t, np.int64), np.zeros(n_t, np.int64)))
-            best = Assignment(ftr, fsc, float("inf"), False)
-        out[t] = best
-    return out
-
-
 def capacitated_assign(
     cost: np.ndarray,            # (N,L,K)
     feasible: np.ndarray,        # (N,L,K)
     stored_gb: np.ndarray,       # (N,L,K) size occupied if cell chosen
     capacity_gb: np.ndarray,     # (L,)
     iters: int = 200,
-    seed: int = 0,
     max_candidates: int = 16,
     tier_groups: Optional[np.ndarray] = None,       # (L,) group id per tier
     group_capacity_gb: Optional[np.ndarray] = None,  # (G,)
     sla_penalty: Optional[np.ndarray] = None,        # (N,L,K) violation units
     sla_lambda: float = 0.0,
 ) -> Assignment:
-    """Vectorized capacitated OPTASSIGN.
+    """Vectorized capacitated OPTASSIGN: :func:`capacitated_assign_batch`
+    on a fleet of one.
 
     The Lagrangian inner solves run as one jitted ``lax.scan`` on device; the
     distinct relaxed assignments it emits are then repaired (argsort eviction)
@@ -706,46 +483,13 @@ def capacitated_assign(
     polish exactly like cost. ``sla_lambda=0`` (or no penalty) leaves
     every array untouched — bit-identical to the pre-SLA solver.
     """
-    if sla_lambda and sla_penalty is not None:
-        cost = (np.asarray(cost, np.float64)
-                + float(sla_lambda) * np.asarray(sla_penalty, np.float64))
-    N, L, K = cost.shape
-    masked = _masked(np.asarray(cost, np.float64), feasible)
-    stored = np.asarray(stored_gb, np.float64)
-    cap = np.asarray(capacity_gb, np.float64)
-    finite_cap = np.isfinite(cap)
-    A, cap_all = _constraint_rows(cap, tier_groups, group_capacity_gb)
-    finite_all = np.isfinite(cap_all)
-
-    if N == 0:
-        z = np.zeros(0, np.int64)
-        return Assignment(z, z.copy(), 0.0, True)
-
-    # lam=0 greedy = the unconstrained optimum; if it fits the capacities it
-    # is optimal outright and the dual ascent can be skipped entirely.
-    cell0 = masked.reshape(N, -1).argmin(1)
-    tier0, scheme0 = cell0 // K, cell0 % K
-    use0 = _chosen_usage(stored, tier0, scheme0)
-    if (~finite_all | (A @ use0 <= cap_all + 1e-9)).all():
-        total = float(masked[np.arange(N), tier0, scheme0].sum())
-        ok = bool(total < BIG)
-        return Assignment(tier0, scheme0, total if ok else float("inf"), ok)
-
-    step0 = _step0(masked, cap_all, finite_all)
-    if tier_groups is None:
-        g_of_t = np.zeros(L, np.int32)
-        gcap = np.array([np.inf])
-    else:
-        g_of_t = np.asarray(tier_groups, np.int32)
-        gcap = np.asarray(group_capacity_gb, np.float64)
-    cells = np.asarray(_lagrangian_scan(
-        jnp.asarray(masked), jnp.asarray(stored), jnp.asarray(cap),
-        jnp.asarray(finite_cap), jnp.asarray(g_of_t), jnp.asarray(gcap),
-        jnp.asarray(np.isfinite(gcap)), jnp.float32(step0), iters))
-
-    uniq = _dedupe_candidates(cells, max_candidates)
-    return _best_from_candidates(uniq, masked, stored, A, cap_all,
-                                 finite_all)
+    return capacitated_assign_batch(
+        [cost], [feasible], [stored_gb], np.asarray(capacity_gb, np.float64),
+        iters=iters, max_candidates=max_candidates, tier_groups=tier_groups,
+        group_capacity_gb=(None if group_capacity_gb is None
+                           else np.asarray(group_capacity_gb, np.float64)),
+        sla_penalties=None if sla_penalty is None else [sla_penalty],
+        sla_lambda=sla_lambda).assignments[0]
 
 
 # ---------------------------------------------------------------- fleet batch
@@ -755,11 +499,10 @@ def _fleet_scan_core(masked, stored, cap, finite_cap, group_of_tier, gcap,
                      axis_name: Optional[str] = None):
     """Batched dual ascent over a padded tenant batch (T, N, L, K).
 
-    The per-tenant body is element-for-element the computation of
-    :func:`_lagrangian_scan` with a leading tenant axis, so each tenant's
-    dual trajectory (and hence its emitted candidate cells) is bit-identical
-    to a standalone solve — padding rows carry BIG cost and zero stored
-    bytes, contributing exactly 0.0 to every usage sum and gradient.
+    Each tenant's dual trajectory (and hence its emitted candidate cells)
+    is its own: padding rows carry BIG cost and zero stored bytes,
+    contributing exactly 0.0 to every usage sum and gradient, so a tenant
+    solved in a fleet emits what it emits solved alone.
 
     On top ride the *shared* fleet-wide constraint rows: ``sgroup_of_tier``
     maps each tier to a shared group whose usage is summed over the whole
@@ -815,11 +558,6 @@ def _fleet_scan_single(masked, stored, cap, finite_cap, group_of_tier, gcap,
 # uncoupled fleets run the lean kernel in fixed-size tenant chunks so one
 # compiled (chunk, N_max) shape is reused for any fleet size
 _FLEET_CHUNK = 64
-
-# below this many alive rows the lockstep descent hands the stragglers to
-# the sequential per-row search (same trajectory, no per-round overhead)
-_LOCKSTEP_TAIL = 8
-
 
 @partial(jax.jit, static_argnames=("iters",))
 def _fleet_scan_plain(masked, stored, cap, finite_cap, step0, iters):
@@ -1213,7 +951,6 @@ def capacitated_assign_batch(
     capacity_gb,                         # (L,) for all tenants, or T x (L,)
     *,
     iters: int = 200,
-    seed: int = 0,
     max_candidates: int = 16,
     tier_groups: Optional[np.ndarray] = None,        # (L,) — one tier space
     group_capacity_gb=None,                          # (G,) or T x (G,)
@@ -1228,12 +965,12 @@ def capacitated_assign_batch(
     Heterogeneous tenant problems are ragged-padded into a
     ``(T, N_max, L, K)`` batch (padding rows: BIG cost, zero stored bytes —
     they contribute zero cost and zero usage, so they never perturb duals or
-    capacities) and run through one batched jitted Lagrangian scan; repair
-    and 1-swap polish then run per tenant on host exactly as in
-    :func:`capacitated_assign`. **With no shared constraints the per-tenant
-    results are bit-identical to T independent** :func:`capacitated_assign`
-    **calls** (pinned by ``tests/test_fleet.py``) — same greedy shortcut,
-    same dual trajectories, same candidate set, same repair/polish.
+    capacities) and run through one batched jitted Lagrangian scan. A
+    tenant whose unconstrained optimum fits its own capacities skips the
+    scan. **With no shared constraints each tenant is finished on its own**
+    by :func:`_best_from_candidates` (dedupe, repair, 1-swap polish), so its
+    result is bit-identical to solving it alone — :func:`capacitated_assign`
+    is this function at T=1 (pinned by ``tests/test_fleet.py``).
 
     ``shared_tier_groups``/``shared_capacity_gb`` add *fleet-wide* capacity
     rows: ``sum over all tenants of use[shared_tier_groups == s] <=
@@ -1252,9 +989,9 @@ def capacitated_assign_batch(
     if (shared_tier_groups is None) != (shared_capacity_gb is None):
         raise ValueError("shared_tier_groups and shared_capacity_gb must be "
                          "passed together")
-    # Soft-SLA term, exactly as in capacitated_assign: folded into the
-    # per-tenant cost tensors before padding, so the weighted penalty rows
-    # ride the batched/sharded fleet scan too. sla_lambda=0 touches nothing.
+    # Soft-SLA term: folded into the per-tenant cost tensors before
+    # padding, so the weighted penalty rows ride the batched/sharded fleet
+    # scan too. sla_lambda=0 touches nothing.
     if sla_lambda and sla_penalties is not None:
         costs = [c if p is None
                  else (np.asarray(c, np.float64)
@@ -1299,7 +1036,8 @@ def capacitated_assign_batch(
         finite_sh = np.zeros(1, bool)
     has_shared = bool(finite_sh.any())
 
-    # lam=0 greedy shortcut, per tenant — identical to capacitated_assign's
+    # lam=0 greedy = the unconstrained optimum; a tenant whose greedy fits
+    # its capacities is optimal outright and skips the dual ascent
     tier0s, scheme0s, use0s, own_ok = [], [], [], []
     for t in range(T):
         cell0 = maskeds[t].reshape(Ns[t], -1).argmin(1) if Ns[t] \
@@ -1365,9 +1103,12 @@ def capacitated_assign_batch(
 
         with tracing.span("assign.finish"):
             if not has_shared:
-                done.update(_batch_candidate_finish(
-                    solve_idx, cells, masked_b, stored_b, maskeds, storeds, As,
-                    cap_alls, finite_alls, Ns, K, max_candidates))
+                for j, t in enumerate(solve_idx):
+                    done[t] = _best_from_candidates(
+                        _dedupe_candidates(cells[:, j, :Ns[t]],
+                                           max_candidates),
+                        maskeds[t], storeds[t], As[t], cap_alls[t],
+                        finite_alls[t])
             else:
                 done.update(_fleet_finish_shared(
                     solve_idx, cells, maskeds, storeds, As, cap_alls,
@@ -1396,7 +1137,6 @@ def capacitated_assign_ref(
     stored_gb: np.ndarray,       # (N,L,K) size occupied if cell chosen
     capacity_gb: np.ndarray,     # (L,)
     iters: int = 200,
-    seed: int = 0,
 ) -> Assignment:
     """Pure-Python reference: Lagrangian + repair + local search (original)."""
     N, L, K = cost.shape

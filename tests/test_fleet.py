@@ -2,6 +2,7 @@
 ragged padding invariance, fleet daemon parity, N=0 corner regressions."""
 
 import dataclasses
+import hashlib
 
 import numpy as np
 import pytest
@@ -373,3 +374,114 @@ def test_chunked_scan_dispatch_preserves_bit_parity(monkeypatch):
                                      [x[3] for x in fleet])
     for single, got in zip(singles, batch.assignments):
         assert _identical(single, got)
+
+
+# ------------------------------------------------------------ golden plans
+def _greedy_use(cost, feas, stored):
+    """Per-tier GB of the unconstrained optimum."""
+    a = greedy_assign(cost, feas)
+    use = np.zeros(cost.shape[1])
+    np.add.at(use, a.tier, stored[np.arange(len(a.tier)), a.tier, a.scheme])
+    return use
+
+
+def _capped_fleet(rng, Ns, sla=False):
+    """Tenants whose most-used tier is capped at 0.3-1.3 times its
+    unconstrained use, so the cap binds for most and not for all.
+    ``sla`` adds penalty tensors, weighed at 0.5 when drawing the caps."""
+    costs, feas, stored, caps, pens = [], [], [], [], []
+    for n in Ns:
+        cost, f, s, _ = _tenant_instance(rng, int(n))
+        pen = (rng.gamma(1.0, 2.0, cost.shape)
+               * (rng.random(cost.shape) < 0.3)) if sla else 0.0
+        use = _greedy_use(cost + 0.5 * pen, f, s)
+        cap = np.full(4, np.inf)
+        cap[int(use.argmax())] = rng.uniform(0.3, 1.3) * use.max()
+        for xs, x in zip((costs, feas, stored, caps, pens),
+                         (cost, f, s, cap, pen)):
+            xs.append(x)
+    return costs, feas, stored, caps, pens
+
+
+def _golden_solve(case):
+    """Solve one golden case; returns every tenant's Assignment."""
+    rng = np.random.default_rng(_GOLDEN[case][0])
+    if case.startswith("single"):
+        cost, feas, stored, caps, pens = _capped_fleet(
+            rng, [60], sla=case == "single_sla")
+        kw = {}
+        if case == "single_tier_and_group_caps":
+            use = _greedy_use(cost[0], feas[0], stored[0])
+            kw = dict(tier_groups=np.array([0, 0, 1, 1]),
+                      group_capacity_gb=np.array(
+                          [0.8 * use[:2].sum(), np.inf]))
+        elif case == "single_sla":
+            kw = dict(sla_penalty=pens[0], sla_lambda=0.5)
+        return [capacitated_assign(cost[0], feas[0], stored[0], caps[0],
+                                   **kw)]
+    if case == "ragged_with_empty_tenant":
+        costs, feas, stored, caps, _ = _capped_fleet(
+            rng, (24, 40, 0, 17, 33, 8, 29))
+        return capacitated_assign_batch(costs, feas, stored,
+                                        caps).assignments
+    Ns = rng.integers(4, 25, 70) if case == "beyond_one_chunk" \
+        else rng.integers(10, 41, 12)
+    costs, feas, stored, caps, pens = _capped_fleet(
+        rng, Ns, sla=case == "fleet_sla")
+    kw = {}
+    if case == "one_tenant_unmeetable":
+        caps[5] = np.full(4, 1e-6)          # below any partition's bytes
+    elif case == "fleet_sla":
+        kw = dict(sla_penalties=pens, sla_lambda=0.5)
+    return capacitated_assign_batch(costs, feas, stored, caps,
+                                    **kw).assignments
+
+
+def _plan_digest(assignments):
+    """SHA-1 of every tenant's tiers then every tenant's schemes, the sum
+    of the feasible tenants' costs, and one feasibility flag per tenant."""
+    vec = np.concatenate([np.asarray(a.tier, np.int64) for a in assignments]
+                         + [np.asarray(a.scheme, np.int64)
+                            for a in assignments])
+    return (hashlib.sha1(vec.tobytes()).hexdigest(),
+            float(sum(a.cost for a in assignments if a.feasible)),
+            "".join("1" if a.feasible else "0" for a in assignments))
+
+
+# case: (seed, SHA-1, cost, feasibility per tenant), read off the solver
+# as it stood before its uncoupled finish was unified
+_GOLDEN = {
+    "single_tier_cap": (
+        101, "0eccb01d9a4a60835e712d5df7e488d842237313",
+        15185.208961501403, "1"),
+    "single_tier_and_group_caps": (
+        102, "dc1641467679185998a567a4cd0a4ad83340c675",
+        26278.82356168516, "1"),
+    "single_sla": (
+        103, "d06d7ca5b9c5a9bccfee9ad56206c4b022a263a9",
+        11790.98827177902, "1"),
+    "ragged_with_empty_tenant": (
+        104, "9fc224e433bc9c196bfb3bf937e2b984845b1468",
+        26764.059925680922, "1" * 7),
+    "one_tenant_unmeetable": (
+        105, "a80b87e83d86cc3e2b9beb4537ef154d25069015",
+        55136.43177521025, "111110111111"),
+    "beyond_one_chunk": (
+        106, "4deff400688fb716bb99329156459fcf4c6f19b3",
+        158070.75267368575, "1" * 70),
+    "fleet_sla": (
+        107, "46cc8ad20dce0cec3434718a1f43ce69d69f0d8f",
+        57046.914898598, "1" * 12),
+}
+
+
+@pytest.mark.parametrize("case", list(_GOLDEN))
+def test_golden_plans(case):
+    """Every capacitated solve lands on the plan pinned here, bit for bit:
+    one tenant or a fleet, tier and group caps, an N=0 tenant, a tenant
+    no repair can make feasible, a fleet past one scan chunk, SLA terms."""
+    _, digest, cost, feasible = _GOLDEN[case]
+    got = _plan_digest(_golden_solve(case))
+    assert got[0] == digest
+    assert got[1] == pytest.approx(cost, rel=1e-12)
+    assert got[2] == feasible

@@ -13,8 +13,7 @@ from repro.core.optassign import (BIG, _chosen_usage, _constraint_rows,
                                   capacitated_assign_batch)
 
 
-def _full_rescan(tier, scheme, use, masked, stored, A, cap_all, finite_all,
-                 max_moves=None):
+def _full_rescan(tier, scheme, use, masked, stored, A, cap_all, finite_all):
     """The descent as it was before it kept its delta matrix: every step
     rebuilds the whole (N, L, K) problem. Verbatim, but for the move count
     it returns."""
@@ -24,12 +23,13 @@ def _full_rescan(tier, scheme, use, masked, stored, A, cap_all, finite_all,
     A_f = A.astype(np.float64)
     any_finite = bool(finite_all.any())
     moves = 0
-    for _ in range(8 * N + 64 if max_moves is None else max_moves):
+    for _ in range(8 * N + 64):
         cur = masked[n_idx, tier, scheme]
         stored_cur = stored[n_idx, tier, scheme]
         if any_finite:
-            # einsum, not @: the lockstep fleet descent replicates this
-            # exact ascending-l accumulation for bitwise-equal trajectories
+            # einsum, not @: this ascending-l accumulation sets the low
+            # bits of the slack, and so which moves fit: the moves this
+            # oracle and the benchmark's reference checks pinned
             use_c = np.einsum("cl,l->c", A_f, use)
             # slack[n, c]: room left in constraint c once n vacates its cell
             slack = ((cap_all - use_c)[None, :]
@@ -53,16 +53,13 @@ def _full_rescan(tier, scheme, use, masked, stored, A, cap_all, finite_all,
     return moves
 
 
-def _both(tier, scheme, use, masked, stored, A, cap_all, finite_all,
-          max_moves=None):
+def _both(tier, scheme, use, masked, stored, A, cap_all, finite_all):
     """Run both descents on copies of one start; assert they agree bit for
     bit and return the move count."""
     got = [tier.copy(), scheme.copy(), use.copy()]
     want = [tier.copy(), scheme.copy(), use.copy()]
-    n_got = _local_search_vec(*got, masked, stored, A, cap_all, finite_all,
-                              max_moves=max_moves)
-    n_want = _full_rescan(*want, masked, stored, A, cap_all, finite_all,
-                          max_moves=max_moves)
+    n_got = _local_search_vec(*got, masked, stored, A, cap_all, finite_all)
+    n_want = _full_rescan(*want, masked, stored, A, cap_all, finite_all)
     assert n_got == n_want
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and np.array_equal(g, w)
@@ -141,29 +138,11 @@ def test_group_rows(N, tier_caps, ints):
           np.isfinite(cap_all))
 
 
-@pytest.mark.parametrize("N,fraction", [(30, 0.25), (300, 0.5),
-                                        (520, 0.9)])
-def test_max_moves_cuts_the_trajectory(N, fraction):
-    """A budget smaller than the trajectory, as the lockstep descent hands
-    its tail rows over: both stop after the same moves."""
-    rng = np.random.default_rng(N + 5)
-    masked, stored, tier, scheme = _instance(rng, N)
-    use = _chosen_usage(stored, tier, scheme)
-    A, cap_all = _constraint_rows(_caps(use, (2,), 1.0), None, None)
-    fin = np.isfinite(cap_all)
-    whole = _both(tier, scheme, use, masked, stored, A, cap_all, fin)
-    budget = int(whole * fraction)
-    assert 0 < budget < whole
-    assert _both(tier, scheme, use, masked, stored, A, cap_all, fin,
-                 max_moves=budget) == budget
-    assert _both(tier, scheme, use, masked, stored, A, cap_all, fin,
-                 max_moves=0) == 0
-
-
 @pytest.mark.parametrize("free_first", [True, False])
 def test_planted_tie_between_a_free_and_a_capped_cell(free_first):
     """Two cells of one row gain the same: the one first in the flattened
-    grid moves, whether it lies in the capped tier or the free one."""
+    grid moves, whether it lies in the capped tier or the free one, and
+    the row stays there, since the other gains nothing on it."""
     capped, free = (2, 1) if free_first else (0, 3)
     masked = np.full((2, 4, 2), 10.0)
     masked[0, capped, 1] = masked[0, free, 0] = 4.0
@@ -177,18 +156,20 @@ def test_planted_tie_between_a_free_and_a_capped_cell(free_first):
     cap[capped] = use[capped]                       # exactly full
     A, cap_all = _constraint_rows(cap, None, None)
     got = [tier.copy(), scheme.copy(), use.copy()]
-    _local_search_vec(*got, masked, stored, A, cap_all, np.isfinite(cap_all),
-                      max_moves=1)
+    assert _local_search_vec(*got, masked, stored, A, cap_all,
+                             np.isfinite(cap_all)) == 1
     want = min(capped * 2 + 1, free * 2)            # flat index in the row
     assert divmod(want, 2) == (got[0][0], got[1][0])
-    _both(tier, scheme, use, masked, stored, A, cap_all, np.isfinite(cap_all))
+    assert _both(tier, scheme, use, masked, stored, A, cap_all,
+                 np.isfinite(cap_all)) == 1
 
 
 def test_planted_tie_between_two_rows():
-    """Two rows' best moves gain the same: the lower row moves first."""
+    """Two rows' best moves gain the same, and the capped tier has room
+    for one of them: the lower row takes it, the other settles for less."""
     masked = np.full((3, 3, 2), 5.0)
     masked[:, 0, 0] = 8.0
-    masked[1, 2, 1] = masked[2, 1, 0] = 2.0
+    masked[1, 1, 1] = masked[2, 1, 0] = 2.0
     stored = np.ones((3, 3, 2))
     tier = np.zeros(3, np.int64)
     scheme = np.zeros(3, np.int64)
@@ -197,8 +178,10 @@ def test_planted_tie_between_two_rows():
                                   None)
     fin = np.isfinite(cap_all)
     got = [tier.copy(), scheme.copy(), use.copy()]
-    _local_search_vec(*got, masked, stored, A, cap_all, fin, max_moves=1)
-    assert (got[0][1], got[1][1]) == (2, 1) and (got[0][2], got[1][2]) == (0, 0)
+    assert _local_search_vec(*got, masked, stored, A, cap_all, fin) == 3
+    assert (got[0][1], got[1][1]) == (1, 1)         # row 1 won tier 1
+    assert (got[0][2], got[1][2]) == (0, 1)         # row 2: cost 5, not 2
+    assert got[2][1] == 1.0
     assert _both(tier, scheme, use, masked, stored, A, cap_all, fin) == 3
 
 
@@ -225,13 +208,12 @@ def _checked(monkeypatch):
     calls = []
     run = optassign._local_search_vec
 
-    def check(tier, scheme, use, masked, stored, A, cap_all, finite_all,
-              max_moves=None):
+    def check(tier, scheme, use, masked, stored, A, cap_all, finite_all):
         moves = _both(tier, scheme, use, masked, stored, A, cap_all,
-                      finite_all, max_moves)
+                      finite_all)
         calls.append(moves)
         return run(tier, scheme, use, masked, stored, A, cap_all,
-                   finite_all, max_moves)
+                   finite_all)
 
     monkeypatch.setattr(optassign, "_local_search_vec", check)
     return calls
@@ -279,8 +261,8 @@ def test_the_residual_shared_rows_of_the_fleet_polish(monkeypatch, seed):
 
 @pytest.mark.parametrize("seed", [2, 3])
 def test_the_capped_fleet_and_the_single_tenant_solve(monkeypatch, seed):
-    """The lockstep descent's tail hand-off (with its move budget) and the
-    single-tenant solve's candidates."""
+    """Every candidate of a capped fleet, each tenant finished on its own,
+    and of the single-tenant solve (the same finish at T = 1)."""
     rng = np.random.default_rng(seed)
     costs, feas, stored = _tenants(rng, 12)
     # tier 1 capped at half of each tenant's unconstrained use of it
